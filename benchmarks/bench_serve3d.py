@@ -18,15 +18,16 @@ every slice and served through the redistributed render path.  Records
   against ground truth crosses the threshold),
 * PSNR parity: the interleaved scheduler must reach the same PSNR per scene
   as sequential single-scene training at equal per-scene iteration counts,
-* scale-out (`scale_out`): a child process forced to a 4-device host
-  topology (``--xla_force_host_platform_device_count=4``) sweeps the
-  session-sharded service over device counts {1, 2, 4} at saturating
-  residency and a fixed cohort cap — scenes/sec must be monotone in device
-  count, the N=1 placement must be bit-identical to the placement-free
-  path, and render p95 is measured under mixed train+render load on the
-  full mesh with the async serving plane.
+* scale-out (`scale_out`): in this process, the session-sharded service
+  is swept over device counts {1, 2, 4} at saturating residency and a fixed
+  cohort cap — scenes/sec must be monotone in device count, the N=1
+  placement must be bit-identical to the placement-free path, and render
+  p95 is measured under mixed train+render load on the full mesh with the
+  async serving plane.  It needs four devices; on CPU,
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` provides them.
 
-    PYTHONPATH=src python -m benchmarks.bench_serve3d [--smoke]
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+    PYTHONPATH=src python -m benchmarks.bench_serve3d [--smoke] [--scale-out]
 
 CI gates these fields against the committed baseline via tools/bench_gate.py.
 """
@@ -34,9 +35,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -61,9 +59,8 @@ def _leaves_equal(a, b):
 
 
 def run_scale_out(smoke: bool = False) -> dict:
-    """The scale-out sweep body; runs inside the forced-4-device child
-    (`--scale-child`).  One process measures every device count so compile
-    caches and machine drift hit each count alike.
+    """The scale-out sweep over DEVICE_COUNTS.  One process measures every
+    device count so compile caches and machine drift hit each count alike.
 
     The workload is a deliberately dispatch-lean regime (8-sample ladder,
     small field, 64 rays): on a host where the forced devices share one
@@ -77,7 +74,7 @@ def run_scale_out(smoke: bool = False) -> dict:
     thread-switch overhead.  The cohort cap is fixed across device counts
     — cohort efficiency is constant, device count is the only variable."""
     assert jax.device_count() >= 4, (
-        f"scale-out child needs 4 devices, got {jax.device_count()} "
+        f"scale-out needs 4 devices, got {jax.device_count()} "
         "(run under XLA_FLAGS=--xla_force_host_platform_device_count=4)")
     scenes = 8
     iters = 16 if smoke else 64
@@ -158,24 +155,6 @@ def run_scale_out(smoke: bool = False) -> dict:
         "render_p95_ms_mixed": lat.get("p95_ms"),
         "render_count_mixed": lat.get("count", 0),
     }
-
-
-def _scale_out_subprocess(smoke: bool) -> dict:
-    """Spawn the forced-topology child and collect its JSON payload."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=4").strip()
-    cmd = [sys.executable, "-m", "benchmarks.bench_serve3d", "--scale-child"]
-    if smoke:
-        cmd.append("--smoke")
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"scale-out child failed:\n{proc.stderr[-4000:]}")
-    for line in proc.stdout.splitlines():
-        if line.startswith("SCALE_OUT_JSON:"):
-            return json.loads(line[len("SCALE_OUT_JSON:"):])
-    raise RuntimeError(f"scale-out child emitted no payload:\n{proc.stdout}")
 
 
 def run(smoke: bool = False):
@@ -334,7 +313,7 @@ def run(smoke: bool = False):
 
     # ---- scale-out: the session-sharded service on a forced device mesh ----
 
-    scale_out = _scale_out_subprocess(smoke)
+    scale_out = run_scale_out(smoke)
 
     lat = tel["render"]
     out = {
@@ -442,12 +421,11 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="4 sessions x few iters x 1 render/slice (CI gate)")
-    ap.add_argument("--scale-child", action="store_true",
-                    help="internal: run the scale-out sweep in this process "
-                         "(expects a forced >=4-device topology) and print "
-                         "its JSON payload instead of the full benchmark")
+    ap.add_argument("--scale-out", action="store_true",
+                    help="run only the scale-out sweep and print its JSON "
+                         "payload (needs four devices)")
     args = ap.parse_args()
-    if args.scale_child:
+    if args.scale_out:
         payload = run_scale_out(smoke=args.smoke)
         print("SCALE_OUT_JSON:" + json.dumps(payload))
         return

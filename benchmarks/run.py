@@ -16,6 +16,8 @@ import sys
 import time
 import traceback
 
+from repro.runtime.compile_cache import enable_compile_cache
+
 # (name, module, output artifact or None) — artifacts land in the repo root
 # and are what CI gates on; suites without one only emit CSV rows.
 SUITES = [
@@ -53,6 +55,7 @@ def main() -> None:
         list_suites()
         return
     only = args.only.split(",") if args.only else None
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
 
     print("name,us_per_call,derived")
     failures = []

@@ -25,6 +25,7 @@ from repro.core import FieldConfig, TrainerConfig, losses, occupancy
 from repro.core.rendering import RenderConfig
 from repro.data import build_dataset
 from repro.obs import export as obs_export, metrics as obs_metrics, trace as obs_trace
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve3d import ReconstructionService
 
 
@@ -51,6 +52,7 @@ def main():
     ap.add_argument("--trace-out", default=None,
                     help="write a Chrome-trace JSON of the demo run")
     args = ap.parse_args()
+    print(f"compile cache: {enable_compile_cache()}")
 
     # the demo always runs instrumented: the progress lines below and the
     # final summary both read from the one obs metrics plane

@@ -21,6 +21,7 @@ from repro.core.rendering import RenderConfig
 from repro.data import build_dataset, RaySampler
 from repro.obs import export as obs_export, trace as obs_trace
 from repro.runtime import DriverConfig, StragglerStats
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -62,13 +63,15 @@ def main():
     ap.add_argument("--metrics-out", default=None,
                     help="write the final metrics snapshot JSON (enables obs)")
     args = ap.parse_args()
+    print(f"compile cache: {enable_compile_cache()}")
 
     if args.trace_out or args.metrics_out:
         obs_trace.configure(enabled=True)
 
     # explicit flag wins; otherwise the registry default ($REPRO_BACKEND / auto)
     be = kernels.set_backend(args.backend) if args.backend else kernels.get_backend()
-    print(f"kernel backend: {be.name} (available: {', '.join(kernels.available_backends())})")
+    print(f"kernel backend: {be} (available: {', '.join(kernels.available_backends())})")
+    print(f"kernel routing: {kernels.routing()}")
 
     render = RenderConfig(n_samples=24)
     scene, ds = build_dataset(seed=args.scene_seed, n_views=12, h=48, w=48,

@@ -4,11 +4,12 @@ Each subpackage ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 public wrapper with backend routing), ref.py (pure-jnp oracle used both for
 allclose validation and as the CPU/autodiff path).
 
-Backend selection is centralized here in the `KernelBackend` registry: every
-ops module resolves its routing through `resolve_backend(...)` instead of
-carrying its own `backend: str` knob.  The one user-facing knob is the
-process-wide default, set via `set_backend(...)`, the `REPRO_BACKEND` env
-var, or left on "auto" (capability detection picks the best available).
+Routing is decided here and nowhere else.  Every ops module resolves its
+backend through `resolve_backend(backend, op=...)`; the one user-facing knob
+is the process-wide request, set via `set_backend(...)`, the `REPRO_BACKEND`
+env var, or left on "auto".  `TPU_LOWERING` says, per op, whether its Pallas
+kernel lowers for the TPU at `FieldConfig()` widths, and why not where it
+does not (tests/test_tpu_compile.py compiles every op it marks as lowering).
 
 Canonical backends:
 
@@ -16,9 +17,11 @@ Canonical backends:
   pallas-interpret Pallas kernels in interpreter mode (validation on CPU)
   pallas-tpu       compiled Pallas kernels (requires a TPU jax backend)
 
-Aliases accepted anywhere a backend name is taken: "pallas" (best pallas
-flavor for the platform: tpu if available, else interpret) and "auto" (tpu
-kernels on TPU, ref elsewhere).
+Aliases accepted anywhere a backend name is taken: "auto" (per op: pallas-tpu
+on a TPU where the op lowers, ref everywhere else) and "pallas" (pallas-tpu on
+a TPU, pallas-interpret elsewhere).  Asking for pallas-tpu, directly or
+through "pallas", on an op without a TPU lowering raises when the op is
+built; nothing falls back silently.
 """
 from __future__ import annotations
 
@@ -46,70 +49,101 @@ PALLAS_TPU = KernelBackend("pallas-tpu", use_pallas=True, interpret=False)
 
 _CANONICAL = {b.name: b for b in (REF, PALLAS_INTERPRET, PALLAS_TPU)}
 
+# op -> None where its Pallas kernel lowers for a TPU v5e at FieldConfig()
+# widths (L=16, F=2, T_D=2^18, ~200k points), else the compiler's refusal.
+TPU_LOWERING: dict[str, str | None] = {
+    "mlp": None,
+    "composite": None,
+    "hash_encode": (
+        "output block (B,1,F) of the (N,L,F) result breaks the (8,128) block "
+        "rule; one whole level table per VMEM block pads to 128 MiB at "
+        "T=2^18 against 16 MiB of scoped VMEM; corner reads are a dynamic "
+        "vector gather"
+    ),
+    "fused_encode": (
+        "same whole-level VMEM table and output block as hash_encode, plus "
+        "an in-kernel argsort (sort has no Mosaic lowering)"
+    ),
+    "fused_step": (
+        "forward sorts in-kernel (sort has no Mosaic lowering) over whole "
+        "level tables in VMEM; backward gathers dynamically from, and keeps "
+        "resident, both full (L,T,F) gradient tables"
+    ),
+    "bum_scatter": (
+        "run-sum gather and commit scatter are dynamic vector indexing "
+        "Mosaic refuses; the whole (T+1,F) table is one VMEM block"
+    ),
+}
+
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - jax not initialized
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def available_backends() -> tuple[str, ...]:
     """Capability detection: which canonical backends can run on this host."""
-    names = ["ref"]
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        names.append("pallas-interpret")
-        if _on_tpu():
-            names.append("pallas-tpu")
-    except ImportError:  # pragma: no cover - pallas ships with jax
-        pass
-    return tuple(names)
+    return ("ref", "pallas-interpret") + (("pallas-tpu",) if _on_tpu() else ())
 
 
-def resolve_backend(backend: str | KernelBackend | None = None) -> KernelBackend:
-    """Map a user-facing name (or None => process default) to a KernelBackend."""
-    if backend is None:
-        return get_backend()
+def _check_name(name: str) -> str:
+    name = name.lower()
+    if name not in ("auto", "pallas") and name not in _CANONICAL:
+        raise ValueError(
+            f"unknown backend {name!r}; expected one of "
+            f"{tuple(_CANONICAL)} or aliases ('auto', 'pallas')"
+        )
+    if name in _CANONICAL and name not in available_backends():
+        raise ValueError(
+            f"backend {name!r} unavailable on this host; have {available_backends()}"
+        )
+    return name
+
+
+def resolve_backend(backend: str | KernelBackend | None = None, *,
+                    op: str) -> KernelBackend:
+    """Map a backend request (None => process default) to the KernelBackend
+    that `op` runs on.  Raises for an unknown op or backend, for a backend
+    this host cannot run, and for pallas-tpu on an op without a TPU
+    lowering."""
+    if op not in TPU_LOWERING:
+        raise ValueError(f"unknown kernel op {op!r}; have {tuple(TPU_LOWERING)}")
     if isinstance(backend, KernelBackend):
-        return backend
-    name = backend.lower()
-    if name == "auto":
-        return PALLAS_TPU if _on_tpu() else REF
-    if name == "pallas":
-        b = PALLAS_TPU if _on_tpu() else PALLAS_INTERPRET
-        if b.name not in available_backends():
-            raise ValueError(
-                f"backend 'pallas' resolves to {b.name!r}, unavailable on this "
-                f"host; have {available_backends()}"
-            )
-        return b
-    if name in _CANONICAL:
-        b = _CANONICAL[name]
-        if b.name not in available_backends():
-            raise ValueError(
-                f"backend {name!r} unavailable on this host; have {available_backends()}"
-            )
-        return b
-    raise ValueError(
-        f"unknown backend {backend!r}; expected one of "
-        f"{tuple(_CANONICAL)} or aliases ('auto', 'pallas')"
-    )
+        be = backend
+    else:
+        name = _check_name(get_backend() if backend is None else backend)
+        if name == "auto":
+            be = PALLAS_TPU if _on_tpu() and TPU_LOWERING[op] is None else REF
+        elif name == "pallas":
+            be = PALLAS_TPU if _on_tpu() else PALLAS_INTERPRET
+        else:
+            be = _CANONICAL[name]
+    if be is PALLAS_TPU and TPU_LOWERING[op] is not None:
+        raise ValueError(
+            f"op {op!r} has no Pallas TPU lowering ({TPU_LOWERING[op]}); "
+            f"use backend 'auto' or 'ref'"
+        )
+    return be
 
 
-_default: KernelBackend | None = None
+def routing(backend: str | None = None) -> dict[str, str]:
+    """op -> canonical backend name each op resolves to for `backend`
+    (None => process default).  What the entry points print."""
+    return {op: resolve_backend(backend, op=op).name for op in TPU_LOWERING}
 
 
-def get_backend() -> KernelBackend:
-    """The process-wide default backend (the single user-facing knob)."""
+_default: str | None = None
+
+
+def get_backend() -> str:
+    """The process-wide backend request (the single user-facing knob)."""
     global _default
     if _default is None:
-        _default = resolve_backend(os.environ.get("REPRO_BACKEND", "auto"))
+        _default = _check_name(os.environ.get("REPRO_BACKEND", "auto"))
     return _default
 
 
-def set_backend(backend: str | KernelBackend) -> KernelBackend:
-    """Set the process-wide default; returns the resolved KernelBackend.
+def set_backend(backend: str) -> str:
+    """Set the process-wide backend request; returns its canonical name.
 
     Binding times differ by op: hash-grid encoders bake routing (forward
     AND merged-backward) at construction, while MLP/composite ops resolve
@@ -119,7 +153,7 @@ def set_backend(backend: str | KernelBackend) -> KernelBackend:
     models or tracing any step function.
     """
     global _default
-    _default = resolve_backend(backend)
+    _default = _check_name(backend)
     return _default
 
 

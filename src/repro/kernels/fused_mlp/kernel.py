@@ -21,17 +21,25 @@ from jax.experimental import pallas as pl
 DEFAULT_BLOCK_ROWS = 512
 
 
+def _lin(x, w_ref, b_ref):
+    # float32 at HIGHEST: the kernel agrees with the float32 `ref` oracle
+    # instead of rounding its operands to bfloat16 on the MXU
+    return jnp.dot(x, w_ref[...].astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32) + b_ref[...]
+
+
 def _mlp2_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
-    h = jnp.maximum(x @ w1_ref[...].astype(jnp.float32) + b1_ref[...], 0.0)
-    o_ref[...] = (h @ w2_ref[...].astype(jnp.float32) + b2_ref[...]).astype(o_ref.dtype)
+    h = jnp.maximum(_lin(x, w1_ref, b1_ref), 0.0)
+    o_ref[...] = _lin(h, w2_ref, b2_ref).astype(o_ref.dtype)
 
 
 def _mlp3_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
-    h1 = jnp.maximum(x @ w1_ref[...].astype(jnp.float32) + b1_ref[...], 0.0)
-    h2 = jnp.maximum(h1 @ w2_ref[...].astype(jnp.float32) + b2_ref[...], 0.0)
-    o_ref[...] = (h2 @ w3_ref[...].astype(jnp.float32) + b3_ref[...]).astype(o_ref.dtype)
+    h1 = jnp.maximum(_lin(x, w1_ref, b1_ref), 0.0)
+    h2 = jnp.maximum(_lin(h1, w2_ref, b2_ref), 0.0)
+    o_ref[...] = _lin(h2, w3_ref, b3_ref).astype(o_ref.dtype)
 
 
 def _full(shape):
@@ -39,7 +47,7 @@ def _full(shape):
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def fused_mlp2(x, w1, b1, w2, b2, *, block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True):
+def fused_mlp2(x, w1, b1, w2, b2, *, block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool):
     n, d_in = x.shape
     h = w1.shape[1]
     d_out = w2.shape[1]
@@ -59,7 +67,7 @@ def fused_mlp2(x, w1, b1, w2, b2, *, block_rows: int = DEFAULT_BLOCK_ROWS, inter
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def fused_mlp3(x, w1, b1, w2, b2, w3, b3, *, block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True):
+def fused_mlp3(x, w1, b1, w2, b2, w3, b3, *, block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool):
     n, d_in = x.shape
     h1 = w1.shape[1]
     h2 = w2.shape[1]

@@ -47,7 +47,7 @@ def _pad_rows(x, multiple):
 
 def _resolve(backend):
     from .. import resolve_backend
-    return resolve_backend(backend)
+    return resolve_backend(backend, op="mlp")
 
 
 # the reference chains split at the pre-activations: mlp2 == _relu_lin(_lin(

@@ -18,7 +18,8 @@ gather of all B*8 corner addresses in point order):
   read row 0 only and contribute exactly zero output.
 
 Grid iterates (point-block, level) like the hash_encode kernel, one level
-table resident in VMEM per step.
+table resident in VMEM per step — which is why, like hash_encode, the op has
+no TPU lowering at `FieldConfig()` widths (see `repro.kernels.TPU_LOWERING`).
 """
 from __future__ import annotations
 
@@ -35,12 +36,13 @@ DEFAULT_BLOCK_POINTS = 256
 
 def _fused_encode_kernel(res_ref, dense_ref, pts_ref, tbl_ref, out_ref):
     """One (point-block, level) step with block-sorted (deduped) corner reads."""
+    l = pl.program_id(1)
     table = tbl_ref[0]  # (T, F)
     pts = pts_ref[...].astype(jnp.float32)  # (B, 3)
     # corner enumeration + sentinel semantics shared with the hash_encode
     # kernel — only the gather strategy below differs
     idx, weights = he_kernel.corner_indices_block(
-        pts, res_ref[0], dense_ref[0], table.shape[0]
+        pts, res_ref[l], dense_ref[l], table.shape[0]
     )
 
     # FMU analogue: sort the block's corner addresses so duplicates occupy
@@ -70,7 +72,7 @@ def fused_encode_pallas(
     dense_flags: jnp.ndarray,
     *,
     block_points: int = DEFAULT_BLOCK_POINTS,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """points (N,3) f32, tables (L,T,F), resolutions/dense_flags (L,) i32.
 
@@ -84,14 +86,14 @@ def fused_encode_pallas(
 
     out = pl.pallas_call(
         _fused_encode_kernel,
-        grid=(n_blocks, num_l),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i, l: (l,)),
-            pl.BlockSpec((1,), lambda i, l: (l,)),
-            pl.BlockSpec((block_points, 3), lambda i, l: (i, 0)),
-            pl.BlockSpec((1, t, f), lambda i, l: (l, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_points, 1, f), lambda i, l: (i, l, 0)),
+        grid_spec=he_kernel.level_grid_spec(
+            n_blocks, num_l, 2,
+            in_specs=[
+                pl.BlockSpec((block_points, 3), lambda i, l, *_: (i, 0)),
+                pl.BlockSpec((1, t, f), lambda i, l, *_: (l, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((block_points, 1, f), lambda i, l, *_: (i, l, 0)),
+        ),
         out_shape=jax.ShapeDtypeStruct((n, num_l, f), jnp.float32),
         interpret=interpret,
     )(resolutions, dense_flags, points, tables)

@@ -99,7 +99,7 @@ def make_fused_encode(
     if residual_policy not in RESIDUAL_POLICIES:
         raise ValueError(f"residual_policy must be one of {RESIDUAL_POLICIES}")
     from .. import resolve_backend
-    be = resolve_backend(backend)
+    be = resolve_backend(backend, op="fused_encode")
     resolutions = tuple(int(r) for r in resolutions)
     table_sizes = tuple(int(t) for t in table_sizes)
     num_l = len(resolutions)

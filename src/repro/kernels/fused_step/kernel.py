@@ -30,11 +30,12 @@ Backward (`fused_step_bwd_pallas`) — grid (point-block,):
   update stream is segment-merged at unique addresses and committed with
   one scatter per run into the VMEM-resident gradient table.
 
-Interpret-mode notes: this container is CPU-only, so both kernels are
-validated with interpret=True against the ref backend (allclose — the
-dedup pre-sum and per-block accumulation reassociate float adds).  The
-backward holds the full (L,T,F) gradient tables resident; a real-TPU
-lowering at L=16/2^18 would tile the level axis like the forward does.
+Both kernels are validated with interpret=True against the ref backend
+(allclose — the dedup pre-sum and per-block accumulation reassociate float
+adds).  Neither lowers for the TPU at `FieldConfig()` widths, so
+`repro.kernels.TPU_LOWERING` routes the op to `ref` there: the forward keeps
+whole level tables in VMEM and sorts in-kernel, and the backward holds both
+full (L,T,F) gradient tables resident.
 """
 from __future__ import annotations
 
@@ -97,10 +98,10 @@ def _fused_step_kernel(res_ref, dd_ref, dc_ref, pts_ref, sh_ref, td_ref, tc_ref,
 
     # --- encode this level for both grids (shared corner geometry) ---
     idx_d, weights = he_kernel.corner_indices_block(
-        pts, res_ref[0], dd_ref[0], td_ref.shape[1]
+        pts, res_ref[l], dd_ref[l], td_ref.shape[1]
     )
     idx_c, _ = he_kernel.corner_indices_block(
-        pts, res_ref[0], dc_ref[0], tc_ref.shape[1]
+        pts, res_ref[l], dc_ref[l], tc_ref.shape[1]
     )
     featd_ref[:, pl.ds(l * f, f)] = _dedup_encode_block(td_ref[0], idx_d, weights)
     featc_ref[:, pl.ds(l * f, f)] = _dedup_encode_block(tc_ref[0], idx_c, weights)
@@ -122,7 +123,7 @@ def _fused_step_kernel(res_ref, dd_ref, dc_ref, pts_ref, sh_ref, td_ref, tc_ref,
 def fused_step_pallas(points, sh, t_density, t_color, mlp_d: dict, mlp_c: dict,
                       resolutions, dense_d, dense_c, *,
                       block_points: int = DEFAULT_BLOCK_POINTS,
-                      interpret: bool = True):
+                      interpret: bool):
     """One-kernel forward.  points (N,3) sentinel-padded to block_points,
     sh (N,S); returns (out_d (N, 1+geo), raw_c (N,3)) f32."""
     n = points.shape[0]
@@ -134,29 +135,28 @@ def fused_step_pallas(points, sh, t_density, t_color, mlp_d: dict, mlp_c: dict,
     d_out = mlp_d["w2"].shape[1]
 
     def const2(a):  # whole array resident, revisited every step
-        return pl.BlockSpec(a.shape, lambda i, l: (0,) * a.ndim)
+        return pl.BlockSpec(a.shape, lambda i, l, *_: (0,) * a.ndim)
 
     weights = [mlp_d[k] for k in _MLP_D_KEYS] + [mlp_c[k] for k in _MLP_C_KEYS]
     _, _, out_d, out_c = pl.pallas_call(
         _fused_step_kernel,
-        grid=(n_blocks, num_l),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i, l: (l,)),             # resolution
-            pl.BlockSpec((1,), lambda i, l: (l,)),             # dense (density)
-            pl.BlockSpec((1,), lambda i, l: (l,)),             # dense (color)
-            pl.BlockSpec((block_points, 3), lambda i, l: (i, 0)),
-            pl.BlockSpec((block_points, s_dim), lambda i, l: (i, 0)),
-            pl.BlockSpec((1, td, f), lambda i, l: (l, 0, 0)),  # one level/step
-            pl.BlockSpec((1, tc, f), lambda i, l: (l, 0, 0)),
-        ] + [const2(w) for w in weights],
-        out_specs=[
-            # feature accumulators: revisited across the level axis, so the
-            # concatenated (B, L*F) block stays VMEM-resident into the epilogue
-            pl.BlockSpec((block_points, num_l * f), lambda i, l: (i, 0)),
-            pl.BlockSpec((block_points, num_l * f), lambda i, l: (i, 0)),
-            pl.BlockSpec((block_points, d_out), lambda i, l: (i, 0)),
-            pl.BlockSpec((block_points, 3), lambda i, l: (i, 0)),
-        ],
+        grid_spec=he_kernel.level_grid_spec(
+            n_blocks, num_l, 3,  # resolution, dense (density), dense (color)
+            in_specs=[
+                pl.BlockSpec((block_points, 3), lambda i, l, *_: (i, 0)),
+                pl.BlockSpec((block_points, s_dim), lambda i, l, *_: (i, 0)),
+                pl.BlockSpec((1, td, f), lambda i, l, *_: (l, 0, 0)),  # one level/step
+                pl.BlockSpec((1, tc, f), lambda i, l, *_: (l, 0, 0)),
+            ] + [const2(w) for w in weights],
+            out_specs=[
+                # feature accumulators: revisited across the level axis, so the
+                # concatenated (B, L*F) block stays VMEM-resident into the epilogue
+                pl.BlockSpec((block_points, num_l * f), lambda i, l, *_: (i, 0)),
+                pl.BlockSpec((block_points, num_l * f), lambda i, l, *_: (i, 0)),
+                pl.BlockSpec((block_points, d_out), lambda i, l, *_: (i, 0)),
+                pl.BlockSpec((block_points, 3), lambda i, l, *_: (i, 0)),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((n, num_l * f), jnp.float32),
             jax.ShapeDtypeStruct((n, num_l * f), jnp.float32),
@@ -267,7 +267,7 @@ def fused_step_bwd_pallas(points, sh, g_d, g_c, t_density, t_color,
                           mlp_d: dict, mlp_c: dict,
                           resolutions, dense_d, dense_c, *,
                           block_points: int = DEFAULT_BLOCK_POINTS,
-                          interpret: bool = True):
+                          interpret: bool):
     """Hand-written one-kernel backward.  Inputs padded like the forward
     (g rows zero on pad lanes); returns (d_t_density, d_t_color, d_mlp_d,
     d_mlp_c, d_sh)."""

@@ -80,7 +80,7 @@ def make_fused_step(
     if residual_policy not in RESIDUAL_POLICIES:
         raise ValueError(f"residual_policy must be one of {RESIDUAL_POLICIES}")
     from .. import resolve_backend
-    be = resolve_backend(backend)
+    be = resolve_backend(backend, op="fused_step")
     resolutions = tuple(int(r) for r in resolutions)
     table_sizes = tuple(int(t) for t in table_sizes)
     assert len(table_sizes) == 2, "fused step covers decomposed fields (2 grids)"

@@ -72,7 +72,7 @@ def bum_scatter_pallas(
     vals_sorted: jnp.ndarray,
     *,
     block: int = DEFAULT_BLOCK,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Merged scatter-add of a sorted update stream into table (T, F).
 

@@ -3,8 +3,11 @@
 `merged_scatter_add` is the production path: sort-by-address + run merge +
 unique scatter.  It is mathematically identical to the naive duplicate
 scatter-add (ref.py) but removes write collisions — the TPU analogue of the
-paper's BUM unit (DESIGN.md §3).  On CPU the merge runs in pure XLA; on TPU
-the commit stage can be served by the Pallas kernel (`use_pallas=True`).
+paper's BUM unit (DESIGN.md §3).  The commit stage routes through the
+`repro.kernels` registry as op "bum_scatter": the XLA segment merge on
+`ref`, the Pallas kernel on `pallas-interpret`.  The kernel has no TPU
+lowering (`repro.kernels.TPU_LOWERING`), so on a TPU `auto` keeps the XLA
+merge.
 """
 from __future__ import annotations
 
@@ -57,37 +60,32 @@ def _segment_commit(table: jnp.ndarray, idx_s: jnp.ndarray, vals_s: jnp.ndarray)
     return table.at[seg_idx].add(summed.astype(table.dtype), mode="drop")
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret", "backend", "presorted"))
+@functools.partial(jax.jit, static_argnames=("backend", "presorted"))
 def merged_scatter_add(
     table: jnp.ndarray,
     idx: jnp.ndarray,
     vals: jnp.ndarray,
     *,
-    use_pallas: bool = False,
-    interpret: bool = True,
     backend=None,
     presorted: bool = False,
 ) -> jnp.ndarray:
     """table (T,F) += vals (M,F) at rows idx (M,) with BUM-merged writes.
 
-    The XLA segment-merge (default) is the production CPU path; `backend`
-    (a `repro.kernels` registry name or KernelBackend) routes the commit
-    stage to the Pallas kernel, overriding the use_pallas/interpret pair
-    (kernel-level escape hatch kept for direct validation).
+    `backend` (a `repro.kernels` registry name or KernelBackend; None =>
+    process default) picks the commit stage: the XLA segment merge on ref,
+    the Pallas kernel otherwise.
 
     presorted=True promises idx is already non-decreasing and skips the
     argsort — the BUM fast path for callers that control update order (the
     fused compacted-path VJP emits its table-gradient stream pre-sorted).
     """
-    if backend is not None:
-        from .. import resolve_backend
-        be = resolve_backend(backend)
-        use_pallas, interpret = be.use_pallas, be.interpret
+    from .. import resolve_backend
+    be = resolve_backend(backend, op="bum_scatter")
     t = table.shape[0]
-    if use_pallas:
+    if be.use_pallas:
         idx_s, vals_s = _sort_updates(idx, vals, t, _kernel.DEFAULT_BLOCK,
                                       presorted=presorted)
-        return _kernel.bum_scatter_pallas(table, idx_s, vals_s, interpret=interpret)
+        return _kernel.bum_scatter_pallas(table, idx_s, vals_s, interpret=be.interpret)
 
     idx_s, vals_s = _sort_updates(idx, vals, t, None, presorted=presorted)
     return _segment_commit(table, idx_s, vals_s)
@@ -100,8 +98,7 @@ def num_unique_addresses(idx: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]]).sum()
 
 
-@functools.partial(jax.jit, static_argnames=("window", "presorted", "use_pallas",
-                                              "interpret", "backend"))
+@functools.partial(jax.jit, static_argnames=("window", "presorted", "backend"))
 def windowed_scatter_add(
     table: jnp.ndarray,
     idx: jnp.ndarray,
@@ -109,8 +106,6 @@ def windowed_scatter_add(
     *,
     window: int = 4096,
     presorted: bool = False,
-    use_pallas: bool = False,
-    interpret: bool = True,
     backend=None,
 ) -> jnp.ndarray:
     """BUM with the paper's *sliding window*: merge duplicates only within
@@ -139,13 +134,11 @@ def windowed_scatter_add(
     presorted=True promises every row of idx is already non-decreasing and
     skips the per-window argsort (the fused-step VJP emits rows through the
     stable order its forward — or recompute-policy backward — planned).
-    `backend` routes each window's commit stage to the Pallas kernel, same
-    contract as `merged_scatter_add`.
+    `backend` picks each window's commit stage, same contract as
+    `merged_scatter_add`.
     """
-    if backend is not None:
-        from .. import resolve_backend
-        be = resolve_backend(backend)
-        use_pallas, interpret = be.use_pallas, be.interpret
+    from .. import resolve_backend
+    be = resolve_backend(backend, op="bum_scatter")
     t = table.shape[0]
     f = table.shape[1]
 
@@ -166,9 +159,9 @@ def windowed_scatter_add(
         if not presorted:
             order = jnp.argsort(wi)
             wi, wv = wi[order], wv[order]
-        if use_pallas:
+        if be.use_pallas:
             wi, wv = _sort_updates(wi, wv, t, _kernel.DEFAULT_BLOCK, presorted=True)
-            return _kernel.bum_scatter_pallas(tbl, wi, wv, interpret=interpret), None
+            return _kernel.bum_scatter_pallas(tbl, wi, wv, interpret=be.interpret), None
         return _segment_commit(tbl, wi, wv), None
 
     # Small static window counts (every per-step caller: the fused-step VJP

@@ -2,23 +2,26 @@
 
 TPU adaptation of the paper's grid cores + FRM unit (DESIGN.md §3):
 
-* Each level's full hash table lives in VMEM (<= 2^18 x 2 x f32 = 2 MB per
-  level, far below the 16 MB/core VMEM budget) — the analogue of the paper's
-  on-chip multi-bank SRAM hash-table storage.
+* Each level's full hash table is one VMEM block — the analogue of the
+  paper's on-chip multi-bank SRAM hash-table storage.  That only fits small
+  tables: VMEM pads the trailing F=2 axis to the 128-lane tiling, so a
+  2^18 x 2 f32 level takes 2^18 x 128 x 4 B = 128 MiB, against v5e's 16 MiB
+  default scoped VMEM limit.  At `FieldConfig()` widths the op therefore has
+  no TPU lowering and `repro.kernels.TPU_LOWERING` routes it to `ref`.
 * Points are processed in VREG-aligned blocks; all 8 corner reads of a block
   are issued as one vectorized gather per level — the batch-granularity
   analogue of the FRM mapping many single reads into one multi-bank access.
 * The grid iterates (point-block, level); BlockSpec index maps stream one
   level table at a time HBM->VMEM, so the VMEM working set is
   |table_level| + |point block| + |out block| regardless of L.
-* Level geometry (resolution, dense flag) is carried in tiny (L,) arrays whose
-  per-step (1,)-blocks behave like scalar prefetch.
+* Level geometry (resolution, dense flag) arrives through scalar prefetch
+  (SMEM) and is indexed by the level grid coordinate.
 
 Layout notes for real TPU lowering: the trailing feature dim F (typically 2)
 is below the 128-lane width; production tables should be stored feature-major
 padded to the lane width, or multiple levels packed per lane group.  The
-kernel is written shape-generically and validated with interpret=True (this
-container is CPU-only); `ops.py` routes to the jnp oracle on CPU.
+kernel is written shape-generically and validated with interpret=True;
+`ops.py` routes to the jnp oracle on CPU and on TPU.
 """
 from __future__ import annotations
 
@@ -27,6 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import ref
 
@@ -76,15 +80,29 @@ def corner_indices_block(pts, resolution, dense, t):
 
     offs_f = offs.astype(jnp.float32)  # (8, 3)
     w = jnp.where(offs_f[None, :, :] > 0, frac[:, None, :], 1.0 - frac[:, None, :])
-    weights = jnp.prod(w, axis=-1) * valid.astype(jnp.float32)[:, None]  # (B, 8)
+    # explicit product: Mosaic has no reduce_prod lowering
+    weights = w[..., 0] * w[..., 1] * w[..., 2] * valid.astype(jnp.float32)[:, None]  # (B, 8)
     return idx, weights
 
 
+def level_grid_spec(n_blocks: int, num_l: int, n_geometry: int, *, in_specs, out_specs):
+    """(point-block, level) grid whose first `n_geometry` operands are (L,)
+    int32 level-geometry arrays passed by scalar prefetch into SMEM — the
+    TPU lowering refuses (1,) VMEM blocks of an (L,) array.  Shared by every
+    hash kernel that walks the level axis."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_geometry, grid=(n_blocks, num_l),
+        in_specs=in_specs, out_specs=out_specs,
+    )
+
+
 def _encode_kernel(res_ref, dense_ref, pts_ref, tbl_ref, out_ref):
-    """One (point-block, level) grid step."""
+    """One (point-block, level) grid step; res/dense are the (L,) SMEM
+    scalar-prefetch operands."""
+    l = pl.program_id(1)
     table = tbl_ref[0]  # (T, F)
     pts = pts_ref[...].astype(jnp.float32)  # (B, 3)
-    idx, weights = corner_indices_block(pts, res_ref[0], dense_ref[0], table.shape[0])
+    idx, weights = corner_indices_block(pts, res_ref[l], dense_ref[l], table.shape[0])
 
     # FRM analogue: one vectorized gather for the whole block's 8 corners.
     feats = table[idx.reshape(-1)].reshape(idx.shape + (table.shape[-1],))
@@ -102,7 +120,7 @@ def hash_encode_pallas(
     dense_flags: jnp.ndarray,
     *,
     block_points: int = DEFAULT_BLOCK_POINTS,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """points (N,3) f32, tables (L,T,F), resolutions/dense_flags (L,) i32.
 
@@ -115,14 +133,14 @@ def hash_encode_pallas(
 
     out = pl.pallas_call(
         _encode_kernel,
-        grid=(n_blocks, num_l),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i, l: (l,)),            # resolution scalar
-            pl.BlockSpec((1,), lambda i, l: (l,)),            # dense flag scalar
-            pl.BlockSpec((block_points, 3), lambda i, l: (i, 0)),
-            pl.BlockSpec((1, t, f), lambda i, l: (l, 0, 0)),  # whole level in VMEM
-        ],
-        out_specs=pl.BlockSpec((block_points, 1, f), lambda i, l: (i, l, 0)),
+        grid_spec=level_grid_spec(
+            n_blocks, num_l, 2,
+            in_specs=[
+                pl.BlockSpec((block_points, 3), lambda i, l, *_: (i, 0)),
+                pl.BlockSpec((1, t, f), lambda i, l, *_: (l, 0, 0)),  # whole level
+            ],
+            out_specs=pl.BlockSpec((block_points, 1, f), lambda i, l, *_: (i, l, 0)),
+        ),
         out_shape=jax.ShapeDtypeStruct((n, num_l, f), jnp.float32),
         interpret=interpret,
     )(resolutions, dense_flags, points, tables)

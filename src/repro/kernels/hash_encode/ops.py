@@ -43,9 +43,8 @@ def _pad_to(x: jnp.ndarray, multiple: int, fill=PAD_SENTINEL):
 
 
 def _forward(points, tables, resolutions, dense_flags, be, block_points: int):
-    if isinstance(be, str) or be is None:  # accept registry names too
-        from .. import resolve_backend
-        be = resolve_backend(be)
+    from .. import resolve_backend
+    be = resolve_backend(be, op="hash_encode")  # accepts registry names too
     if be.use_pallas:
         pts, n = _pad_to(points, block_points)
         out = _kernel.hash_encode_pallas(
@@ -94,7 +93,7 @@ def make_hash_encode(
     Returns encode(points (N,3), tables (L,T,F)) -> (N, L*F) float32.
     """
     from .. import resolve_backend
-    be = resolve_backend(backend)
+    be = resolve_backend(backend, op="hash_encode")
     resolutions = tuple(int(r) for r in resolutions)
     dense_flags = tuple(
         bool(x) for x in ref.level_is_dense(np.asarray(resolutions), table_size)

@@ -1,9 +1,11 @@
 """Pallas TPU kernel: ray-march composition (Eq. 1) over ray blocks.
 
 Rays are independent, so the kernel blocks over rays and keeps a whole ray's
-sample axis resident in VMEM; the transmittance prefix product is a cumsum on
-the VPU.  This keeps the (R, S) intermediates out of HBM — the rendering
-analogue of the accelerator doing Step 4 on-chip.
+sample axis resident in VMEM; the transmittance prefix product is an
+exclusive prefix sum of sigma*delta, computed as a triangular matmul on the
+MXU (`repro.kernels.prefix`: Mosaic has no cumsum).  This keeps the (R, S)
+intermediates out of HBM — the rendering analogue of the accelerator doing
+Step 4 on-chip.
 """
 from __future__ import annotations
 
@@ -13,13 +15,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..prefix import prefix_sum
+
 DEFAULT_BLOCK_RAYS = 128
 
 
 def _composite_kernel(sigma_ref, rgb_ref, deltas_ref, ts_ref, color_ref, depth_ref, opac_ref):
     tau = sigma_ref[...].astype(jnp.float32) * deltas_ref[...].astype(jnp.float32)
-    cum = jnp.cumsum(tau, axis=-1)
-    transmittance = jnp.exp(-(cum - tau))
+    transmittance = jnp.exp(-prefix_sum(tau, exclusive=True))
     alpha = 1.0 - jnp.exp(-tau)
     weights = transmittance * alpha  # (B, S)
     color_ref[...] = jnp.sum(
@@ -32,7 +35,7 @@ def _composite_kernel(sigma_ref, rgb_ref, deltas_ref, ts_ref, color_ref, depth_r
 
 
 @functools.partial(jax.jit, static_argnames=("block_rays", "interpret"))
-def composite_pallas(sigma, rgb, deltas, ts, *, block_rays: int = DEFAULT_BLOCK_RAYS, interpret: bool = True):
+def composite_pallas(sigma, rgb, deltas, ts, *, block_rays: int = DEFAULT_BLOCK_RAYS, interpret: bool):
     """sigma (R,S), rgb (R,S,3), deltas (R,S), ts (R,S) -> (color, depth, opacity)."""
     r, s = sigma.shape
     assert r % block_rays == 0

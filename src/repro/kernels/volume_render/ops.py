@@ -58,7 +58,7 @@ def composite(sigma, rgb, deltas, ts, *, backend=None, block_rays: int = _kernel
     """Render rays. 'ref' returns RenderOut (incl. weights, autodiff path);
     pallas backends return RenderOut with weights=None (fused kernel)."""
     from .. import resolve_backend
-    be = resolve_backend(backend)
+    be = resolve_backend(backend, op="composite")
     if be.use_pallas:
         color, depth, opac = _composite_pallas(
             sigma, rgb, deltas, ts, block_rays, be.interpret

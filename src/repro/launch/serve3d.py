@@ -33,6 +33,7 @@ from ..core.rendering import RenderConfig, sphere_poses
 from ..data import build_dataset
 from ..obs import export as obs_export
 from ..obs import trace as obs_trace
+from ..runtime.compile_cache import enable_compile_cache
 from ..serve3d import GuardConfig, ReconstructionService
 from ..testing import faults
 
@@ -142,12 +143,13 @@ def main(argv=None):
     ap.add_argument("--metrics-every", type=int, default=0,
                     help="print a serve3d metrics snapshot every N quanta")
     args = ap.parse_args(argv)
+    print(f"compile cache: {enable_compile_cache()}")
 
     if args.trace_out or args.metrics_out or args.metrics_every:
         obs_trace.configure(enabled=True)
 
     be = kernels.set_backend(args.backend) if args.backend else kernels.get_backend()
-    print(f"kernel backend: {be.name}")
+    print(f"kernel backend: {be}  routing: {kernels.routing()}")
 
     if args.chaos:
         if args.scenes < 2:
@@ -220,5 +222,19 @@ def main(argv=None):
     return tel
 
 
+def failures(tel: dict) -> list[str]:
+    """What kept a finished run from serving every session: sessions that
+    did not reach DONE (quarantined included) and renders answered with an
+    error.  Empty when the run was clean."""
+    bad = [f"{p['session_id']} ended {p['status']}"
+           for p in tel["sessions"] if p["status"] != "done"]
+    d = tel["render"]["degraded"]
+    if d["failed"]:
+        bad.append(f"{d['failed']} render(s) failed ({d['last_failure']})")
+    return bad
+
+
 if __name__ == "__main__":
-    main()
+    problems = failures(main())
+    if problems:
+        raise SystemExit("serve3d: " + "; ".join(problems))

@@ -137,6 +137,7 @@ class RenderResult(NamedTuple):
     # (guard rollback/quarantine) — pixels are valid, freshness is not
     stale: bool = False
     level: int = 0        # resolution level the pixels were rendered at
+    device: Any = None    # the device the batched render's output came from
 
 
 class RenderError(NamedTuple):
@@ -183,6 +184,7 @@ class RenderService:
         # degradation telemetry (always live, like the latency histograms)
         self.expired = 0
         self.failed = 0
+        self.last_failure: str | None = None
         self.shed_drains = 0
         self.drains = 0
         # per-session serving telemetry, backed by obs Histograms (bounded
@@ -406,10 +408,12 @@ class RenderService:
         for key, items in groups.items():
             try:
                 results.extend(self._render_group(*key, items))
-            except Exception:
+            except Exception as e:
                 # batched render died (device fault / injected render_fail):
                 # re-queue the group's requests for another attempt, then
-                # answer the exhausted ones with a typed error
+                # answer the exhausted ones with a typed error; the cause
+                # stays visible in latency_stats()["last_failure"]
+                self.last_failure = f"{type(e).__name__}: {e}"
                 requeue = []
                 for req, _snap in items:
                     req.attempts += 1
@@ -499,6 +503,7 @@ class RenderService:
                                   dirs[:, i:i + chunk], ts)
                 rgb_chunks.append(rgb_c)
                 dep_chunks.append(dep_c)
+            (ran_on,) = rgb_chunks[0].devices()
             rgb = np.asarray(jnp.concatenate(rgb_chunks, axis=1))[:, :n]
             dep = np.asarray(jnp.concatenate(dep_chunks, axis=1))[:, :n]
 
@@ -533,6 +538,7 @@ class RenderService:
                 latency_s=lat,
                 stale=sid in self._stale,
                 level=int(level),
+                device=ran_on,
             ))
         return out
 
@@ -551,6 +557,7 @@ class RenderService:
         degraded = {
             "expired": self.expired,
             "failed": self.failed,
+            "last_failure": self.last_failure,
             "shed_fraction": self.shed_drains / self.drains if self.drains else 0.0,
             "stale_sessions": sorted(self._stale),
         }

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from repro.kernels.fused_path.reuse import EncodingReuseCache, stream_reuse_mask
 from repro.kernels.hash_encode import ref as he_ref
 
-from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 RES = (4, 8, 16)
 T = {"density": 64, "color": 32}
@@ -30,7 +30,7 @@ def _points(rng, n=32):
     return jnp.asarray(rng.random((n, 3), dtype=np.float32) * (1 - 1e-6))
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 2**31 - 1))
 def test_cached_encodings_never_stale(seed):
     """Any sequence of {row update, grid update, fold, encode} keeps cached

@@ -2,7 +2,7 @@
 import numpy as np
 import jax.numpy as jnp
 import pytest
-from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.fused_mlp import ref as mlp_ref, ops as mlp_ops
 from repro.kernels.volume_render import ref as vr_ref, ops as vr_ops

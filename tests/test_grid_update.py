@@ -2,7 +2,7 @@
 import numpy as np
 import jax.numpy as jnp
 import pytest
-from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.grid_update import ref, ops, kernel
 
@@ -14,7 +14,8 @@ def test_merged_matches_naive(t, f, m, use_pallas, rng):
     idx = jnp.asarray(rng.integers(0, t, size=m).astype(np.int32))
     vals = jnp.asarray(rng.normal(size=(m, f)).astype(np.float32))
     naive = ref.scatter_add(table, idx, vals)
-    merged = ops.merged_scatter_add(table, idx, vals, use_pallas=use_pallas)
+    backend = "pallas-interpret" if use_pallas else "ref"
+    merged = ops.merged_scatter_add(table, idx, vals, backend=backend)
     np.testing.assert_allclose(np.asarray(merged), np.asarray(naive), atol=1e-4, rtol=1e-5)
 
 
@@ -66,7 +67,8 @@ def test_presorted_pallas_matches(rng):
     vals = jnp.asarray(rng.normal(size=(m, 2)).astype(np.float32))
     table = jnp.zeros((t, 2), jnp.float32)
     naive = ref.scatter_add(table, idx, vals)
-    fast = ops.merged_scatter_add(table, idx, vals, use_pallas=True, presorted=True)
+    fast = ops.merged_scatter_add(table, idx, vals, backend="pallas-interpret",
+                                  presorted=True)
     np.testing.assert_allclose(np.asarray(fast), np.asarray(naive), atol=1e-4, rtol=1e-5)
 
 
@@ -127,8 +129,9 @@ def test_windowed_stacked_pallas_matches_xla(rng):
     table = jnp.asarray(rng.normal(size=(t, f)).astype(np.float32))
     idx = jnp.asarray(np.sort(rng.integers(0, t, size=(w, m)).astype(np.int32), axis=1))
     vals = jnp.asarray(rng.normal(size=(w, m, f)).astype(np.float32))
-    got = ops.windowed_scatter_add(table, idx, vals, presorted=True, use_pallas=True)
-    want = ops.windowed_scatter_add(table, idx, vals, presorted=True)
+    got = ops.windowed_scatter_add(table, idx, vals, presorted=True,
+                                   backend="pallas-interpret")
+    want = ops.windowed_scatter_add(table, idx, vals, presorted=True, backend="ref")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-5)
 
 

@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from _jax_compat import requires_new_sharding_api
 
 from repro.models.config import ModelConfig, MoEConfig, SSMConfig
 from repro.models import moe, ssm
@@ -19,7 +18,6 @@ def _moe_cfg(n_routed=8, top_k=2, n_shared=1, ep=True):
     )
 
 
-@requires_new_sharding_api
 def test_moe_ep_matches_dense_single_shard(rng):
     """With model-axis size 1 the EP path must agree with the dense oracle
     exactly (no drops possible)."""
@@ -33,7 +31,6 @@ def test_moe_ep_matches_dense_single_shard(rng):
     np.testing.assert_allclose(np.asarray(ep), np.asarray(dense), atol=1e-4, rtol=1e-4)
 
 
-@requires_new_sharding_api
 def test_moe_decode_path(rng):
     cfg = _moe_cfg()
     params = moe.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)

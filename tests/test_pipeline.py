@@ -152,17 +152,20 @@ def test_cube_root():
 
 def test_backend_registry():
     assert "ref" in kernels.available_backends()
-    ref = kernels.resolve_backend("ref")
+    ref = kernels.resolve_backend("ref", op="mlp")
     assert not ref.use_pallas
-    pal = kernels.resolve_backend("pallas")  # alias: best flavor for platform
+    pal = kernels.resolve_backend("pallas", op="mlp")  # alias: best flavor for platform
     assert pal.use_pallas
     with pytest.raises(ValueError):
-        kernels.resolve_backend("cuda")
+        kernels.resolve_backend("cuda", op="mlp")
+    with pytest.raises(ValueError):
+        kernels.resolve_backend("ref", op="no_such_op")
     # the one user-facing knob: process default; explicit names still override
     prev = kernels.get_backend()
     try:
-        assert kernels.set_backend("ref") == ref
-        assert kernels.resolve_backend(None) == ref
+        assert kernels.set_backend("ref") == "ref"
+        assert kernels.resolve_backend(None, op="mlp") == ref
+        assert set(kernels.routing().values()) == {"ref"}
     finally:
         kernels.set_backend(prev)
 

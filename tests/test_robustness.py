@@ -11,13 +11,14 @@ import numpy as np
 import jax
 import pytest
 
-from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import CheckpointManager
 from repro.core import FieldConfig, TrainerConfig, occupancy
 from repro.core.rendering import RenderConfig
 from repro.core.trainer import tree_all_finite
 from repro.data import build_dataset
+from repro.launch.serve3d import failures
 from repro.serve3d import (
     DONE, QUARANTINED, GuardConfig, ReconstructionService, RenderError,
     RenderService, SceneSession, SnapshotStore,
@@ -246,6 +247,7 @@ def test_quarantine_after_max_retries_keeps_service_alive():
     assert svc.scheduler.all_done          # quarantine is terminal
     assert tel["guard"]["quarantined"] == ["scene-000"]
     assert tel["guard"]["rollbacks"] == 2  # max_retries, then ejected
+    assert failures(tel) == ["scene-000 ended quarantined"]  # CLI exits non-zero
 
     # the quarantined scene still serves: last-good snapshot, marked stale
     snap = svc.store.latest("scene-000")
@@ -325,6 +327,8 @@ def test_render_group_failure_retries_then_succeeds():
     assert svc.renderer.drain() == []          # attempt 1 fails, re-queued
     (res,) = svc.renderer.drain()              # attempt 2 succeeds
     assert not isinstance(res, RenderError) and res.rgb.shape == (12, 12, 3)
+    assert res.device == jax.devices()[0]      # unplaced: the default device
+    assert failures(svc.telemetry()) == []     # a retried render is no failure
 
 
 def test_render_group_failure_exhausts_to_typed_error():
@@ -337,6 +341,8 @@ def test_render_group_failure_exhausts_to_typed_error():
     assert isinstance(err, RenderError)
     assert err.request_id == rid and err.error == "render_failed"
     assert svc.renderer.failed == 1 and svc.renderer.pending == 0
+    (bad,) = failures(svc.telemetry())          # CLI exits non-zero, with the cause
+    assert bad.startswith("1 render(s) failed (InjectedFault:")
 
 
 def test_overload_shedding_degrades_before_dropping():

@@ -26,7 +26,7 @@ from repro.core.pipeline import RenderPipeline
 from repro.core.rendering import RenderConfig
 from repro.data import build_dataset, RaySampler
 
-from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 FIELD_CFG = FieldConfig(n_levels=4, max_resolution=64, log2_table_density=12,
                         log2_table_color=10)
@@ -53,7 +53,7 @@ def _draw_case(seed: int, use_ema: bool):
         None if ema is None else jnp.asarray(ema), budget
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(seed=st.integers(0, 2**31 - 1), use_ema=st.booleans())
 def test_budget_conservation_and_floor(seed, use_ema):
     """(1) sum(S'_i) <= budget by construction, every ray's floor of 1
@@ -68,7 +68,7 @@ def test_budget_conservation_and_floor(seed, use_ema):
     assert (np.asarray(valid).sum(axis=1) == s_ray).all()
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(seed=st.integers(0, 2**31 - 1), use_ema=st.booleans())
 def test_cdf_monotone_and_normalized(seed, use_ema):
     """(2) each ray's weighted CDF is monotone non-decreasing and ends at
@@ -82,7 +82,7 @@ def test_cdf_monotone_and_normalized(seed, use_ema):
     np.testing.assert_allclose(cdf[:, -1], 1.0, rtol=1e-5)
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(seed=st.integers(0, 2**31 - 1), use_ema=st.booleans())
 def test_deltas_sum_to_live_length(seed, use_ema):
     """(3) valid-lane quadrature deltas sum per ray to the live segment
@@ -100,7 +100,7 @@ def test_deltas_sum_to_live_length(seed, use_ema):
     np.testing.assert_allclose(d.sum(axis=1), target, rtol=1e-5, atol=1e-6)
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(seed=st.integers(0, 2**31 - 1), use_ema=st.booleans())
 def test_samples_land_in_live_strata(seed, use_ema):
     """(4) every valid placed sample falls in a live stratum (rays with no
@@ -214,7 +214,7 @@ def test_v3_equals_v2_under_uniform_weights_allocation():
 # ---- occupancy mass/mask degeneration (ISSUE 9 small fix) ----
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 2**31 - 1))
 def test_ray_segment_mass_degrades_to_mask(seed):
     """Thresholding the EMA-weighted mass recovers the binary mask exactly:

@@ -359,6 +359,9 @@ def test_forced_host_device_count_subprocess():
     """End-to-end mesh run under a forced 4-device host topology: placement
     spread, async serving, and N=4 == N=1 params bit-identity."""
     env = dict(os.environ)
+    # forced host devices are CPU devices; pinning the child to the CPU also
+    # keeps it off an accelerator this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4").strip()
     env["PYTHONPATH"] = os.pathsep.join(

@@ -16,14 +16,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from _jax_compat import requires_new_sharding_api
 
 from repro.configs import get_smoke_config
 from repro.models.lm import LM
 from repro.parallel import sharding as shd
 
 
-@requires_new_sharding_api
 def test_param_specs_cover_tree():
     cfg = get_smoke_config("qwen3-8b")
     model = LM(cfg)
@@ -37,7 +35,6 @@ def test_param_specs_cover_tree():
     assert n_specs == n_params
 
 
-@requires_new_sharding_api
 def test_tp_rules_shard_heads_and_ffn():
     import dataclasses
     cfg = dataclasses.replace(get_smoke_config("qwen3-8b"), n_kv_heads=4)
@@ -53,7 +50,6 @@ def test_tp_rules_shard_heads_and_ffn():
     assert specs["embed"] == jax.sharding.PartitionSpec("model", None)
 
 
-@requires_new_sharding_api
 def test_indivisible_heads_stay_replicated():
     cfg = get_smoke_config("qwen2-vl-2b")  # 4 q heads, 2 kv heads
     model = LM(cfg)
@@ -96,7 +92,6 @@ _SUBPROCESS_DRYRUN = textwrap.dedent("""
 """)
 
 
-@requires_new_sharding_api
 @pytest.mark.parametrize("arch,kind,expect_coll", [
     ("qwen3-8b", "train", "all-reduce"),          # DP gradient sync
     ("deepseek-v2-lite-16b", "train", "all-to-all"),  # EP dispatch

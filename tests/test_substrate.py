@@ -5,12 +5,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from _hypothesis_shim import given, settings, strategies as st  # noqa: F401
-from _jax_compat import requires_new_sharding_api
 
 from repro.optim import AdamW, schedule, clip_by_global_norm
 from repro.checkpoint import CheckpointManager
 from repro.runtime import TrainDriver, DriverConfig, StragglerStats, resume_or_init
+from repro.runtime import compile_cache
 from repro.data import SyntheticLMStream, LMStreamConfig
 from repro.parallel.collectives import compressed_psum_mean, _quantize, _dequantize
 
@@ -77,7 +76,6 @@ def test_checkpoint_detects_corruption(tmp_path):
     assert meta["step"] == 1  # fell back to the previous valid snapshot
 
 
-@requires_new_sharding_api
 def test_checkpoint_elastic_mesh_change(tmp_path):
     """Save on one layout, restore sharded onto another (elastic scaling)."""
     from jax.sharding import PartitionSpec as P, NamedSharding
@@ -171,7 +169,6 @@ def test_quantize_roundtrip_error_bounded(rng):
     assert err.max() <= float(s) * 0.5 + 1e-6
 
 
-@requires_new_sharding_api
 def test_compressed_psum_matches_exact_mean():
     """Single-device axis: compressed psum == quantized identity; multi-step
     error feedback drives the accumulated bias to zero."""
@@ -190,3 +187,22 @@ def test_compressed_psum_matches_exact_mean():
         exact = exact + g
     rel = float(jnp.linalg.norm(total - exact) / jnp.linalg.norm(exact))
     assert rel < 0.01, rel
+
+
+def test_compile_cache_dir_from_env_or_checkout(monkeypatch, tmp_path):
+    """The entry points' persistent compile cache: $JAX_COMPILATION_CACHE_DIR
+    when set (and then nothing is changed), else .jax_cache/ at the
+    checkout root."""
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == jax.config.jax_compilation_cache_dir
+        assert os.path.basename(path) == ".jax_cache"
+        assert os.path.samefile(os.path.dirname(path),
+                                os.path.join(os.path.dirname(__file__), ".."))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

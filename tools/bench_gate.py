@@ -117,7 +117,7 @@ SPECS: dict[str, list[Rule]] = {
         # must never roll back a fault-free run (false-positive detector)
         Rule("guard.overhead_frac", max=0.01),
         Rule("guard.rollbacks", max=0),
-        # scale-out (device-mesh session sharding, forced 4-device child):
+        # scale-out (device-mesh session sharding, forced 4-device host):
         # scenes/sec must be monotone non-decreasing in device count with a
         # strict 1 -> 4 win (full runs only — smoke slices are too short to
         # resolve the dispatch/compute overlap), and the N=1 placement must
