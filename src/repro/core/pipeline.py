@@ -495,12 +495,14 @@ class RenderPipeline:
         """
         b, s = ts.shape
         n = b * s
-        # stage spans are host-side: under jit they time the *trace* of each
-        # stage (the compile-side cost breakdown); in eager use they time
-        # execution.  Either way they never touch array values.
-        with _trace.span("pipeline/sample", cat="pipeline"):
+        # each stage is a named scope, so its ops carry the stage's name in
+        # their op_name metadata; with obs on it is also a host span, which
+        # under jit times the *trace* of the stage (the compile-side cost
+        # breakdown) and in eager use times execution.  Neither touches
+        # array values.
+        with _trace.stage("pipeline/sample", cat="pipeline"):
             flat_pts, flat_dirs, unit = self.generate_samples(origins, dirs, ts)
-        with _trace.span("pipeline/cull", cat="pipeline"):
+        with _trace.stage("pipeline/cull", cat="pipeline"):
             live = self.cull(flat_pts, unit, bitfield=bitfield, mask_fn=mask_fn)
 
         deltas = probe_live_frac = None
@@ -510,7 +512,7 @@ class RenderPipeline:
         # silently exceeding the ceiling
         if (self.redistribute_on and bitfield is not None
                 and budget is not None and int(budget) >= b):
-            with _trace.span("pipeline/redistribute", cat="pipeline"):
+            with _trace.stage("pipeline/redistribute", cat="pipeline"):
                 # the uniform candidates' liveness doubles as the (jittered)
                 # occupancy probe; their mean is exactly the uniform sampler's
                 # live fraction — what the budget controller calibrates against
@@ -539,7 +541,7 @@ class RenderPipeline:
                     live = self.cull(flat_pts, unit, bitfield=bitfield, mask_fn=mask_fn)
 
         if budget is None:
-            with _trace.span("pipeline/shade", cat="pipeline",
+            with _trace.stage("pipeline/shade", cat="pipeline",
                              args={"points": n, "dense": True}):
                 sigma, rgb = self.shade(params, unit, flat_dirs)
             sigma = jnp.where(live, sigma, 0.0)
@@ -548,10 +550,10 @@ class RenderPipeline:
             points_queried = n
         else:
             budget = min(int(budget), n)
-            with _trace.span("pipeline/compact", cat="pipeline",
+            with _trace.stage("pipeline/compact", cat="pipeline",
                              args={"budget": budget}):
                 plan = self.compact(live, budget, unit)
-            with _trace.span("pipeline/shade", cat="pipeline",
+            with _trace.stage("pipeline/shade", cat="pipeline",
                              args={"points": budget, "dense": False}):
                 sigma_c, rgb_c = self.shade(
                     params, unit[plan.idx], flat_dirs[plan.idx],
@@ -566,7 +568,7 @@ class RenderPipeline:
             n_live, overflow = plan.n_live, plan.overflow
             points_queried = budget
 
-        with _trace.span("pipeline/composite", cat="pipeline"):
+        with _trace.stage("pipeline/composite", cat="pipeline"):
             out = self.composite(sigma, rgb, ts, deltas)
         out.update(
             live_fraction=(

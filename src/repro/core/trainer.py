@@ -913,7 +913,8 @@ def train_cohort(
         where = [None] * m  # member -> (group, row) for this iteration
         obs_on = obs_trace.enabled()
         for g in groups:
-            batch = g.sample(samplers, key_batch, cfg.n_rays)
+            with obs_trace.span("trainer/sample", cat="trainer"):
+                batch = g.sample(samplers, key_batch, cfg.n_rays)
             if obs_on:
                 # compile/execute split: a step variant's first-ever call is
                 # the one that traces + compiles it (its cache key appears on
@@ -999,10 +1000,11 @@ def train_cohort(
         if (local_i + 1) % log_every == 0 or local_i == iters - 1:
             wall = obs_trace.clock() - t0
             for g in groups:
-                loss_h = np.asarray(g.last_loss)
-                live_h = np.asarray(g.last_aux["live_fraction"])
-                pts_h = np.asarray(g.last_aux["points_queried"])
-                ov_h = np.asarray(g.last_aux["overflow"])
+                with obs_trace.span("trainer/log_sync", cat="trainer"):
+                    loss_h = np.asarray(g.last_loss)
+                    live_h = np.asarray(g.last_aux["live_fraction"])
+                    pts_h = np.asarray(g.last_aux["points_queried"])
+                    ov_h = np.asarray(g.last_aux["overflow"])
                 for r, k in enumerate(g.members):
                     h = histories[k]
                     h["step"].append(i + 1)

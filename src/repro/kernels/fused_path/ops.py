@@ -45,6 +45,7 @@ from . import kernel as _kernel
 from ..hash_encode import ref as he_ref
 from ..hash_encode import ops as he_ops
 from ..grid_update import ops as gu_ops
+from ...obs import trace as _trace
 
 DEFAULT_BLOCK_POINTS = _kernel.DEFAULT_BLOCK_POINTS
 RESIDUAL_POLICIES = ("stash", "recompute")
@@ -152,9 +153,14 @@ def make_fused_encode(
 
     @jax.custom_vjp
     def encode(points, *tables):
-        return _forward(points, tables)
+        with _trace.stage("hash_grid/fwd", cat="kernels"):
+            return _forward(points, tables)
 
     def encode_fwd(points, *tables):
+        with _trace.stage("hash_grid/fwd", cat="kernels"):
+            return _encode_fwd(points, tables)
+
+    def _encode_fwd(points, tables):
         protos = tuple(jnp.zeros((0,), t.dtype) for t in tables)
         if residual_policy == "recompute":
             # Only the points alias crosses to the backward; the plan is
@@ -172,6 +178,10 @@ def make_fused_encode(
         return outs, (points, w_stack, streams, protos)
 
     def encode_bwd(res_pack, g_out):
+        with _trace.stage("hash_grid/bwd", cat="kernels"):
+            return _encode_bwd(res_pack, g_out)
+
+    def _encode_bwd(res_pack, g_out):
         points, w_stack, streams, protos = res_pack
         if streams is None:  # recompute policy
             w_stack, streams, _, _ = _plan(points)

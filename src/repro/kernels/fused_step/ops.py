@@ -155,16 +155,16 @@ def make_fused_step(
     @jax.custom_vjp
     def step(points, sh, t_density, t_color, mlp_d, mlp_c):
         # non-differentiated calls (pure renders) run the primal, not
-        # step_fwd — span both so serve-side traces see the kernel too
-        with _trace.span("kernels/fused_step/fwd", cat="kernels",
+        # step_fwd — name both so serve-side traces see the kernel too
+        with _trace.stage("kernels/fused_step/fwd", cat="kernels",
                          args={"policy": residual_policy, "backend": be.name}):
             return _forward(points, sh, (t_density, t_color), mlp_d, mlp_c)
 
     def step_fwd(points, sh, t_density, t_color, mlp_d, mlp_c):
-        # host-side span: under jit this times the forward's trace (the
-        # compile-side cost of the one-kernel step); with REPRO_OBS=jax the
-        # jax.profiler annotation carries the name into XLA device traces
-        with _trace.span("kernels/fused_step/fwd", cat="kernels",
+        # a stage: its ops carry the name as op_name metadata; with obs on,
+        # also a host span timing the forward's trace (the compile-side cost
+        # of the one-kernel step)
+        with _trace.stage("kernels/fused_step/fwd", cat="kernels",
                          args={"policy": residual_policy, "backend": be.name}):
             tables = (t_density, t_color)
             if be.use_pallas or residual_policy == "recompute":
@@ -183,7 +183,7 @@ def make_fused_step(
             return outs, (points, sh, protos, mlp_d, mlp_c, stash)
 
     def step_bwd(res, g_out):
-        with _trace.span("kernels/fused_step/bwd", cat="kernels",
+        with _trace.stage("kernels/fused_step/bwd", cat="kernels",
                          args={"policy": residual_policy, "backend": be.name}):
             return _step_bwd(res, g_out)
 

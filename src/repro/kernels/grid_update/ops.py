@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from . import kernel as _kernel
+from ...obs import trace as _trace
 
 
 def _sort_updates(idx: jnp.ndarray, vals: jnp.ndarray, table_size: int, pad_to: int | None,
@@ -29,17 +30,18 @@ def _sort_updates(idx: jnp.ndarray, vals: jnp.ndarray, table_size: int, pad_to: 
     is stable, sorting an already-sorted stream is the identity permutation,
     so both paths are bit-identical on sorted input.
     """
-    if presorted:
-        idx_s, vals_s = idx, vals
-    else:
-        order = jnp.argsort(idx)
-        idx_s = idx[order]
-        vals_s = vals[order]
-    if pad_to is not None and idx.shape[0] % pad_to != 0:
-        pad = pad_to - idx.shape[0] % pad_to
-        idx_s = jnp.concatenate([idx_s, jnp.full((pad,), table_size, jnp.int32)])
-        vals_s = jnp.concatenate([vals_s, jnp.zeros((pad,) + vals.shape[1:], vals.dtype)])
-    return idx_s, vals_s
+    with _trace.stage("grid_update/sort", cat="kernels"):
+        if presorted:
+            idx_s, vals_s = idx, vals
+        else:
+            order = jnp.argsort(idx)
+            idx_s = idx[order]
+            vals_s = vals[order]
+        if pad_to is not None and idx.shape[0] % pad_to != 0:
+            pad = pad_to - idx.shape[0] % pad_to
+            idx_s = jnp.concatenate([idx_s, jnp.full((pad,), table_size, jnp.int32)])
+            vals_s = jnp.concatenate([vals_s, jnp.zeros((pad,) + vals.shape[1:], vals.dtype)])
+        return idx_s, vals_s
 
 
 def _segment_commit(table: jnp.ndarray, idx_s: jnp.ndarray, vals_s: jnp.ndarray) -> jnp.ndarray:
@@ -51,13 +53,15 @@ def _segment_commit(table: jnp.ndarray, idx_s: jnp.ndarray, vals_s: jnp.ndarray)
     construction (same ops, same segment count).
     """
     m = idx_s.shape[0]
-    is_start = jnp.concatenate([jnp.ones((1,), bool), idx_s[1:] != idx_s[:-1]])
-    seg_id = jnp.cumsum(is_start) - 1  # (M,)
-    summed = jax.ops.segment_sum(vals_s.astype(jnp.float32), seg_id, num_segments=m)
-    # Representative address per run; empty trailing segments get INT32_MAX
-    # from segment_min's identity and are dropped by the scatter.
-    seg_idx = jax.ops.segment_min(idx_s, seg_id, num_segments=m)
-    return table.at[seg_idx].add(summed.astype(table.dtype), mode="drop")
+    with _trace.stage("grid_update/merge", cat="kernels"):
+        is_start = jnp.concatenate([jnp.ones((1,), bool), idx_s[1:] != idx_s[:-1]])
+        seg_id = jnp.cumsum(is_start) - 1  # (M,)
+        summed = jax.ops.segment_sum(vals_s.astype(jnp.float32), seg_id, num_segments=m)
+        # Representative address per run; empty trailing segments get INT32_MAX
+        # from segment_min's identity and are dropped by the scatter.
+        seg_idx = jax.ops.segment_min(idx_s, seg_id, num_segments=m)
+    with _trace.stage("grid_update/commit", cat="kernels"):
+        return table.at[seg_idx].add(summed.astype(table.dtype), mode="drop")
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "presorted"))
@@ -85,7 +89,8 @@ def merged_scatter_add(
     if be.use_pallas:
         idx_s, vals_s = _sort_updates(idx, vals, t, _kernel.DEFAULT_BLOCK,
                                       presorted=presorted)
-        return _kernel.bum_scatter_pallas(table, idx_s, vals_s, interpret=be.interpret)
+        with _trace.stage("grid_update/commit", cat="kernels"):
+            return _kernel.bum_scatter_pallas(table, idx_s, vals_s, interpret=be.interpret)
 
     idx_s, vals_s = _sort_updates(idx, vals, t, None, presorted=presorted)
     return _segment_commit(table, idx_s, vals_s)
@@ -157,11 +162,13 @@ def windowed_scatter_add(
     def commit_window(tbl, inp):
         wi, wv = inp
         if not presorted:
-            order = jnp.argsort(wi)
-            wi, wv = wi[order], wv[order]
+            with _trace.stage("grid_update/sort", cat="kernels"):
+                order = jnp.argsort(wi)
+                wi, wv = wi[order], wv[order]
         if be.use_pallas:
             wi, wv = _sort_updates(wi, wv, t, _kernel.DEFAULT_BLOCK, presorted=True)
-            return _kernel.bum_scatter_pallas(tbl, wi, wv, interpret=be.interpret), None
+            with _trace.stage("grid_update/commit", cat="kernels"):
+                return _kernel.bum_scatter_pallas(tbl, wi, wv, interpret=be.interpret), None
         return _segment_commit(tbl, wi, wv), None
 
     # Small static window counts (every per-step caller: the fused-step VJP

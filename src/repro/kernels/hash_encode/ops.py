@@ -24,6 +24,7 @@ import numpy as np
 from . import ref
 from . import kernel as _kernel
 from ..grid_update import ops as grid_update_ops
+from ...obs import trace as _trace
 
 
 # Padding sentinel for point batches that aren't a block multiple.  Real
@@ -45,18 +46,19 @@ def _pad_to(x: jnp.ndarray, multiple: int, fill=PAD_SENTINEL):
 def _forward(points, tables, resolutions, dense_flags, be, block_points: int):
     from .. import resolve_backend
     be = resolve_backend(be, op="hash_encode")  # accepts registry names too
-    if be.use_pallas:
-        pts, n = _pad_to(points, block_points)
-        out = _kernel.hash_encode_pallas(
-            pts,
-            tables,
-            jnp.asarray(resolutions, jnp.int32),
-            jnp.asarray(dense_flags, jnp.int32),
-            block_points=block_points,
-            interpret=be.interpret,
-        )
-        return out[:n]
-    return ref.hash_encode(points, tables, resolutions)
+    with _trace.stage("hash_grid/fwd", cat="kernels"):
+        if be.use_pallas:
+            pts, n = _pad_to(points, block_points)
+            out = _kernel.hash_encode_pallas(
+                pts,
+                tables,
+                jnp.asarray(resolutions, jnp.int32),
+                jnp.asarray(dense_flags, jnp.int32),
+                block_points=block_points,
+                interpret=be.interpret,
+            )
+            return out[:n]
+        return ref.hash_encode(points, tables, resolutions)
 
 
 def _corner_updates(points, resolutions, dense_flags, table_size, grad):
@@ -67,14 +69,15 @@ def _corner_updates(points, resolutions, dense_flags, table_size, grad):
     """
     num_l = grad.shape[1]
     all_idx, all_val = [], []
-    for l in range(num_l):
-        res = int(resolutions[l])
-        corners, weights = ref._level_corners(points, res)  # (N,8,3), (N,8)
-        idx = ref.corner_index(corners, res, table_size, bool(dense_flags[l]))
-        upd = weights[..., None] * grad[:, l, None, :]  # (N, 8, F)
-        all_idx.append((idx + l * table_size).reshape(-1))
-        all_val.append(upd.reshape(-1, grad.shape[-1]))
-    return jnp.concatenate(all_idx), jnp.concatenate(all_val)
+    with _trace.stage("hash_grid/bwd/stream", cat="kernels"):
+        for l in range(num_l):
+            res = int(resolutions[l])
+            corners, weights = ref._level_corners(points, res)  # (N,8,3), (N,8)
+            idx = ref.corner_index(corners, res, table_size, bool(dense_flags[l]))
+            upd = weights[..., None] * grad[:, l, None, :]  # (N, 8, F)
+            all_idx.append((idx + l * table_size).reshape(-1))
+            all_val.append(upd.reshape(-1, grad.shape[-1]))
+        return jnp.concatenate(all_idx), jnp.concatenate(all_val)
 
 
 def make_hash_encode(
@@ -110,19 +113,20 @@ def make_hash_encode(
         return out, (points, jnp.zeros((0,), tables.dtype))
 
     def encode_bwd(res, g):
-        points, tproto = res
-        tdtype = tproto.dtype
-        grad = g.reshape(points.shape[0], num_l, n_features).astype(jnp.float32)
-        idx, vals = _corner_updates(points, resolutions, dense_flags, table_size, grad)
-        flat = jnp.zeros((num_l * table_size, n_features), jnp.float32)
-        if merged_backward:
-            # commit stage follows the encoder's backend: pallas flavors use
-            # the BUM scatter kernel, ref stays on the XLA segment merge
-            flat = grid_update_ops.merged_scatter_add(flat, idx, vals, backend=be)
-        else:
-            flat = flat.at[idx].add(vals)
-        grad_tables = flat.reshape(num_l, table_size, n_features).astype(tdtype)
-        return jnp.zeros_like(points), grad_tables
+        with _trace.stage("hash_grid/bwd", cat="kernels"):
+            points, tproto = res
+            tdtype = tproto.dtype
+            grad = g.reshape(points.shape[0], num_l, n_features).astype(jnp.float32)
+            idx, vals = _corner_updates(points, resolutions, dense_flags, table_size, grad)
+            flat = jnp.zeros((num_l * table_size, n_features), jnp.float32)
+            if merged_backward:
+                # commit stage follows the encoder's backend: pallas flavors use
+                # the BUM scatter kernel, ref stays on the XLA segment merge
+                flat = grid_update_ops.merged_scatter_add(flat, idx, vals, backend=be)
+            else:
+                flat = flat.at[idx].add(vals)
+            grad_tables = flat.reshape(num_l, table_size, n_features).astype(tdtype)
+            return jnp.zeros_like(points), grad_tables
 
     encode.defvjp(encode_fwd, encode_bwd)
     return encode

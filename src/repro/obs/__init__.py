@@ -5,7 +5,9 @@ Three pieces, one knob:
 
 * `repro.obs.trace` — host-side spans (`span` context manager / `traced`
   decorator) collected into a bounded ring buffer, thread-aware, with
-  optional ``jax.profiler.TraceAnnotation`` pass-through;
+  optional ``jax.profiler.TraceAnnotation`` pass-through; `stage` names a
+  stage of jitted code as a ``jax.named_scope`` (op metadata a device
+  trace shows) and, with the knob on, also as a span;
 * `repro.obs.metrics` — Counter / Gauge / Histogram behind a process-global
   `Registry` with deterministic JSON snapshots;
 * `repro.obs.export` — ``chrome://tracing`` / Perfetto JSON for spans,
@@ -26,13 +28,13 @@ from __future__ import annotations
 
 from . import export, metrics, trace
 from .metrics import REGISTRY, Counter, Gauge, Histogram, Registry
-from .trace import clock, configure, enabled, instant, set_enabled, span, traced
+from .trace import clock, configure, enabled, instant, set_enabled, span, stage, traced
 
 __all__ = [
     "export", "metrics", "trace",
     "REGISTRY", "Counter", "Gauge", "Histogram", "Registry",
     "clock", "configure", "enabled", "instant", "set_enabled", "span",
-    "traced",
+    "stage", "traced",
 ]
 
 

@@ -35,9 +35,17 @@ the compile-vs-execute split the trainer reports.  With
 ``jax_annotations`` on (``REPRO_OBS=jax``), spans also enter a
 ``jax.profiler.TraceAnnotation`` so host spans line up with XLA device
 traces captured via ``jax.profiler.trace``.
+
+Code that runs under ``jax.jit`` names its stages with `stage` instead: a
+stage is always a ``jax.named_scope``, so XLA keeps its name in the
+``op_name`` metadata of every op the stage emits (a device trace shows it
+as each op's program path), and, when the knob is on, also the trace-time
+host span above.  The scope writes HLO metadata only while jax traces; a
+cached execution runs the same device program with no host work.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import threading
@@ -175,6 +183,26 @@ def span(name: str, cat: str = "obs", args: dict | None = None):
     if not _STATE.enabled:
         return NULL
     return Span(name, cat, args)
+
+
+@contextlib.contextmanager
+def stage(name: str, cat: str = "obs", args: dict | None = None):
+    """A named stage of code that jax traces: ``jax.named_scope(name)``
+    always, plus `span(name, cat, args)` when observability is on.
+
+    A stage opened inside a stage whose name prefixes its own
+    (``hash_grid/bwd/stream`` inside ``hash_grid/bwd``) scopes only the
+    remainder, so the op path reads ``.../hash_grid/bwd/stream/...``."""
+    import jax
+
+    outer = getattr(_tls, "stage", "")
+    scope = name[len(outer) + 1:] if outer and name.startswith(outer + "/") else name
+    _tls.stage = name
+    try:
+        with jax.named_scope(scope), span(name, cat, args):
+            yield
+    finally:
+        _tls.stage = outer
 
 
 def traced(name: str | None = None, cat: str = "obs"):

@@ -21,6 +21,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import trace as _trace
+
 
 class AdamWState(NamedTuple):
     step: jnp.ndarray  # int32 scalar
@@ -69,6 +71,10 @@ class AdamW:
 
     def apply(self, params, grads, state: AdamWState, mask=None):
         """Returns (new_params, new_state).  mask: pytree of bools, True=update."""
+        with _trace.stage("optimizer/adam", cat="optim"):
+            return self._apply(params, grads, state, mask)
+
+    def _apply(self, params, grads, state: AdamWState, mask):
         if self.clip_norm is not None:
             grads, _ = clip_by_global_norm(grads, self.clip_norm)
 

@@ -467,45 +467,50 @@ class RenderService:
         dev_ctx = (jax.default_device(device) if device is not None
                    else contextlib.nullcontext())
         with dev_ctx:
-            origins, dirs = [], []
-            n = chunk = None
-            for req, _snap in padded:
-                o, d, n, chunk = image_rays(req.pose, h, w, focal, eval_chunk)
-                origins.append(o)
-                dirs.append(d)
-            origins = jnp.stack(origins)   # (G, n_pad, 3)
-            dirs = jnp.stack(dirs)
-            params = jax.tree.map(
-                lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]),
-                *[snap.params for _req, snap in padded],
-            )
-            ts = rendering.sample_ts(None, chunk, render_cfg)
+            # the view's host phases, each its own span so a device-idle gap
+            # inside the render group is put down to one of them
+            with obs_trace.span("serve3d/render_prepare", cat="serve3d"):
+                origins, dirs = [], []
+                n = chunk = None
+                for req, _snap in padded:
+                    o, d, n, chunk = image_rays(req.pose, h, w, focal, eval_chunk)
+                    origins.append(o)
+                    dirs.append(d)
+                origins = jnp.stack(origins)   # (G, n_pad, 3)
+                dirs = jnp.stack(dirs)
+                params = jax.tree.map(
+                    lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]),
+                    *[snap.params for _req, snap in padded],
+                )
+                ts = rendering.sample_ts(None, chunk, render_cfg)
 
-            # redistributed path needs every snapshot to carry occupancy; a
-            # params-only snapshot (external publisher) falls back to dense
-            redistribute = (samples_per_ray is not None
-                            and all(snap.occ is not None for _req, snap in padded))
-            if redistribute:
-                occ_ema = jnp.stack(
-                    [jnp.asarray(snap.occ[0]) for _req, snap in padded])
-                occ_step = jnp.asarray(
-                    [int(snap.occ[1]) for _req, snap in padded], jnp.int32)
-                fn_r = batched_redistributed_render_fn(
-                    field_cfg, render_cfg, occ_cfg, chunk, g_pad, samples_per_ray,
-                    redistribute_v3=bool(redistribute_v3))
-                fn = lambda p, o, d, t: fn_r(p, o, d, t, occ_ema, occ_step)
-            else:
-                fn = batched_render_fn(field_cfg, render_cfg, chunk, g_pad)
+                # redistributed path needs every snapshot to carry occupancy; a
+                # params-only snapshot (external publisher) falls back to dense
+                redistribute = (samples_per_ray is not None
+                                and all(snap.occ is not None for _req, snap in padded))
+                if redistribute:
+                    occ_ema = jnp.stack(
+                        [jnp.asarray(snap.occ[0]) for _req, snap in padded])
+                    occ_step = jnp.asarray(
+                        [int(snap.occ[1]) for _req, snap in padded], jnp.int32)
+                    fn_r = batched_redistributed_render_fn(
+                        field_cfg, render_cfg, occ_cfg, chunk, g_pad, samples_per_ray,
+                        redistribute_v3=bool(redistribute_v3))
+                    fn = lambda p, o, d, t: fn_r(p, o, d, t, occ_ema, occ_step)
+                else:
+                    fn = batched_render_fn(field_cfg, render_cfg, chunk, g_pad)
 
-            rgb_chunks, dep_chunks = [], []
-            for i in range(0, origins.shape[1], chunk):
-                rgb_c, dep_c = fn(params, origins[:, i:i + chunk],
-                                  dirs[:, i:i + chunk], ts)
-                rgb_chunks.append(rgb_c)
-                dep_chunks.append(dep_c)
+            with obs_trace.span("serve3d/render_dispatch", cat="serve3d"):
+                rgb_chunks, dep_chunks = [], []
+                for i in range(0, origins.shape[1], chunk):
+                    rgb_c, dep_c = fn(params, origins[:, i:i + chunk],
+                                      dirs[:, i:i + chunk], ts)
+                    rgb_chunks.append(rgb_c)
+                    dep_chunks.append(dep_c)
             (ran_on,) = rgb_chunks[0].devices()
-            rgb = np.asarray(jnp.concatenate(rgb_chunks, axis=1))[:, :n]
-            dep = np.asarray(jnp.concatenate(dep_chunks, axis=1))[:, :n]
+            with obs_trace.span("serve3d/render_readback", cat="serve3d"):
+                rgb = np.asarray(jnp.concatenate(rgb_chunks, axis=1))[:, :n]
+                dep = np.asarray(jnp.concatenate(dep_chunks, axis=1))[:, :n]
 
         now = obs_trace.clock()
         obs_on = obs_trace.enabled()

@@ -272,3 +272,140 @@ def test_serve3d_service_metrics_and_trace(obs_on, tmp_path):
         "trainer/step_compile", "trainer/occ_update",
         "pipeline/sample", "pipeline/shade", "pipeline/composite",
     ]) == []
+
+
+# ---- device scopes: stages named in the compiled program's op metadata ----
+
+
+def test_stage_records_a_host_span_only_when_on():
+    was = trace.enabled()
+    try:
+        obs.reset()
+        trace.set_enabled(False)
+        with trace.stage("hash_grid/bwd", cat="kernels"):
+            pass
+        assert trace.events() == []
+        trace.set_enabled(True)
+        with trace.stage("hash_grid/bwd", cat="kernels"):
+            with trace.stage("hash_grid/bwd/stream", cat="kernels"):
+                pass
+        assert [(e.name, e.cat, e.depth) for e in trace.events()] == [
+            ("hash_grid/bwd/stream", "kernels", 1), ("hash_grid/bwd", "kernels", 0)]
+    finally:
+        obs.reset()
+        trace.set_enabled(was)
+
+
+# tiny dense training cell: N = 16 rays x 6 samples = 96 points, L = 2, so
+# the corner stream has 96 x 8 x 2 = 1536 rows (tables hold 512 and 128)
+_N_RAYS, _N_SAMPLES, _LEVELS = 16, 6, 2
+_STREAM_ROWS = _N_RAYS * _N_SAMPLES * 8 * _LEVELS
+_TRAIN_SCOPES = ("pipeline/sample", "pipeline/cull", "pipeline/shade", "pipeline/composite",
+                 "hash_grid/fwd", "hash_grid/bwd", "hash_grid/bwd/stream", "grid_update/sort",
+                 "grid_update/merge", "grid_update/commit", "optimizer/adam")
+_RENDER_SCOPES = ("pipeline/sample", "pipeline/cull", "pipeline/redistribute",
+                  "pipeline/compact", "pipeline/shade", "pipeline/composite", "hash_grid/fwd")
+
+
+def _field_cfg():
+    from repro.core import FieldConfig
+    return FieldConfig(n_levels=_LEVELS, max_resolution=16, log2_table_density=8,
+                       log2_table_color=6, hidden=16)
+
+
+def _train_step_hlo(freeze_color: bool) -> str:
+    """The tiny dense step's optimized HLO, compiled afresh."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import Field, Instant3DTrainer, TrainerConfig
+    from repro.core import trainer as trainer_lib
+    from repro.core.rendering import RayBatch, RenderConfig
+
+    fcfg = _field_cfg()
+    cfg = TrainerConfig(n_rays=_N_RAYS, render=RenderConfig(n_samples=_N_SAMPLES),
+                        use_occupancy=False)
+    state = jax.eval_shape(Instant3DTrainer(Field(fcfg), cfg).init, jax.random.PRNGKey(0))
+    shape = jax.ShapeDtypeStruct
+    member = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: shape((1,) + a.shape, a.dtype), tree)
+    args = (member(state.params), member(state.opt_state),
+            RayBatch(*[shape((1, _N_RAYS, 3), jnp.float32)] * 3),
+            shape((_N_RAYS, _N_SAMPLES), jnp.float32), member(state.occ_state.density_ema))
+    trainer_lib._COHORT_STEP_CACHE.clear()
+    step = trainer_lib.cohort_step_fn(fcfg, cfg, freeze_color, False, None, False, 1)
+    return step.lower(*args).compile().as_text()
+
+
+def _op_names(hlo: str) -> list:
+    import re
+    return re.findall(r'op_name="([^"]*)"', hlo)
+
+
+def _in_scope(path: str, scope: str) -> bool:
+    import re
+    return re.search(rf"(?:^|[/(;]){re.escape(scope)}(?:$|[/):;])", path) is not None
+
+
+@pytest.mark.parametrize("freeze_color", [False, True], ids=["both_grids", "color_frozen"])
+def test_train_step_names_every_stage_in_op_metadata(freeze_color):
+    names = _op_names(_train_step_hlo(freeze_color))
+    missing = [s for s in _TRAIN_SCOPES if not any(_in_scope(n, s) for n in names)]
+    assert missing == []
+    # the grid-update stages run inside the hash grid's backward (paths from
+    # the step's root; a scatter's reducer region names only its own op)
+    assert all(_in_scope(n, "hash_grid/bwd") for n in names
+               if n.startswith("jit(") and _in_scope(n, "grid_update/merge"))
+
+
+def test_served_render_chunk_names_its_stages_in_op_metadata():
+    import jax
+    import jax.numpy as jnp
+    from repro.core import Field, occupancy
+    from repro.core import trainer as trainer_lib
+    from repro.core.rendering import RenderConfig
+
+    fcfg, rcfg = _field_cfg(), RenderConfig(n_samples=8)
+    occ = occupancy.OccupancyConfig(resolution=16)
+    g, chunk, spr = 2, 32, 2
+    params = jax.eval_shape(Field(fcfg).init, jax.random.PRNGKey(0))
+    shape = jax.ShapeDtypeStruct
+    args = (jax.tree.map(lambda a: shape((g,) + a.shape, a.dtype), params),
+            shape((g, chunk, 3), jnp.float32), shape((g, chunk, 3), jnp.float32),
+            shape((chunk, 8), jnp.float32), shape((g, occ.resolution ** 3), jnp.float32),
+            shape((g,), jnp.int32))
+    fn = trainer_lib.batched_redistributed_render_fn(fcfg, rcfg, occ, chunk, g, spr)
+    names = _op_names(fn.lower(*args).compile().as_text())
+    assert [s for s in _RENDER_SCOPES if not any(_in_scope(n, s) for n in names)] == []
+    assert not any(_in_scope(n, "hash_grid/bwd") for n in names)
+
+
+def test_corner_stream_ops_lie_under_the_hash_grid_backward():
+    import re
+    hlo = _train_step_hlo(False)
+    stream = [line for line in hlo.splitlines()
+              if re.search(rf"= \(?[a-z0-9]+\[{_STREAM_ROWS}[,\]]", line) and "op_name=" in line]
+    assert stream
+    outside = [line.strip()[:160] for line in stream
+               if not _in_scope(re.search(r'op_name="([^"]*)"', line).group(1),
+                                "hash_grid/bwd")]
+    assert outside == []
+
+
+def test_stages_change_nothing_but_op_metadata(monkeypatch):
+    """With `stage` a no-op the step compiles to the same optimized HLO, op
+    metadata and the source-location tables aside: a stage costs nothing
+    on the device and nothing in a cached execution."""
+    import contextlib
+    import re
+
+    def bare(hlo):
+        return re.sub(r", metadata=\{[^}]*\}", "", hlo.split("\nFileNames")[0])
+
+    texts = []
+    for off in (False, True):
+        if off:
+            monkeypatch.setattr(trace, "stage", lambda *a, **k: contextlib.nullcontext())
+        texts.append(_train_step_hlo(False))
+    assert any("hash_grid/bwd" in n for n in _op_names(texts[0]))
+    assert not any("hash_grid/bwd" in n for n in _op_names(texts[1]))
+    assert bare(texts[0]) == bare(texts[1])
