@@ -9,7 +9,9 @@ backend through `resolve_backend(backend, op=...)`; the one user-facing knob
 is the process-wide request, set via `set_backend(...)`, the `REPRO_BACKEND`
 env var, or left on "auto".  `TPU_LOWERING` says, per op, whether its Pallas
 kernel lowers for the TPU at `FieldConfig()` widths, and why not where it
-does not (tests/test_tpu_compile.py compiles every op it marks as lowering).
+does not (tests/test_tpu_compile.py compiles every op it marks as lowering);
+`TPU_MAX_WIDTH` bounds the rows an op's TPU kernel takes, so the route
+follows the rows' width where the op passes it.
 
 Canonical backends:
 
@@ -69,10 +71,14 @@ TPU_LOWERING: dict[str, str | None] = {
         "level tables in VMEM; backward gathers dynamically from, and keeps "
         "resident, both full (L,T,F) gradient tables"
     ),
-    "bum_scatter": (
-        "run-sum gather and commit scatter are dynamic vector indexing "
-        "Mosaic refuses; the whole (T+1,F) table is one VMEM block"
-    ),
+    "bum_scatter": None,
+}
+
+# op -> widest row (the last dimension) its TPU kernel takes.  Under `auto`
+# wider rows take `ref`, as an op without a TPU lowering does, and an
+# explicit pallas-tpu request for them raises.
+TPU_MAX_WIDTH: dict[str, int] = {
+    "bum_scatter": 8,   # 3F bfloat16 pieces in 24 sublanes; the hash grids' F=2 rows, not an LM embedding's
 }
 
 
@@ -100,26 +106,30 @@ def _check_name(name: str) -> str:
 
 
 def resolve_backend(backend: str | KernelBackend | None = None, *,
-                    op: str) -> KernelBackend:
+                    op: str, width: int | None = None) -> KernelBackend:
     """Map a backend request (None => process default) to the KernelBackend
-    that `op` runs on.  Raises for an unknown op or backend, for a backend
-    this host cannot run, and for pallas-tpu on an op without a TPU
-    lowering."""
+    that `op` runs on, for rows `width` wide where the op's TPU kernel
+    takes rows up to `TPU_MAX_WIDTH[op]`.  Raises for an unknown op or
+    backend, for a backend this host cannot run, and for pallas-tpu on an
+    op without a TPU lowering at that width."""
     if op not in TPU_LOWERING:
         raise ValueError(f"unknown kernel op {op!r}; have {tuple(TPU_LOWERING)}")
+    why = TPU_LOWERING[op]
+    if why is None and width is not None and width > TPU_MAX_WIDTH.get(op, width):
+        why = f"the TPU kernel takes rows up to {TPU_MAX_WIDTH[op]} wide, not {width}"
     if isinstance(backend, KernelBackend):
         be = backend
     else:
         name = _check_name(get_backend() if backend is None else backend)
         if name == "auto":
-            be = PALLAS_TPU if _on_tpu() and TPU_LOWERING[op] is None else REF
+            be = PALLAS_TPU if _on_tpu() and why is None else REF
         elif name == "pallas":
             be = PALLAS_TPU if _on_tpu() else PALLAS_INTERPRET
         else:
             be = _CANONICAL[name]
-    if be is PALLAS_TPU and TPU_LOWERING[op] is not None:
+    if be is PALLAS_TPU and why is not None:
         raise ValueError(
-            f"op {op!r} has no Pallas TPU lowering ({TPU_LOWERING[op]}); "
+            f"op {op!r} has no Pallas TPU lowering ({why}); "
             f"use backend 'auto' or 'ref'"
         )
     return be

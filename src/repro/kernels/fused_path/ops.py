@@ -101,6 +101,7 @@ def make_fused_encode(
         raise ValueError(f"residual_policy must be one of {RESIDUAL_POLICIES}")
     from .. import resolve_backend
     be = resolve_backend(backend, op="fused_encode")
+    commit_be = resolve_backend(backend, op="bum_scatter", width=n_features)
     resolutions = tuple(int(r) for r in resolutions)
     table_sizes = tuple(int(t) for t in table_sizes)
     num_l = len(resolutions)
@@ -199,7 +200,7 @@ def make_fused_encode(
             flat = jnp.zeros((num_l * table_sizes[g], n_features), jnp.float32)
             if merged_backward:
                 flat = gu_ops.merged_scatter_add(
-                    flat, addr_sorted, vals[order], presorted=True, backend=be
+                    flat, addr_sorted, vals[order], presorted=True, backend=commit_be
                 )
             else:
                 flat = flat.at[addr_sorted].add(vals[order])

@@ -1,13 +1,15 @@
 """Jitted wrappers for BUM-style merged grid updates.
 
 `merged_scatter_add` is the production path: sort-by-address + run merge +
-unique scatter.  It is mathematically identical to the naive duplicate
+one write per row.  It is mathematically identical to the naive duplicate
 scatter-add (ref.py) but removes write collisions — the TPU analogue of the
-paper's BUM unit (DESIGN.md §3).  The commit stage routes through the
-`repro.kernels` registry as op "bum_scatter": the XLA segment merge on
-`ref`, the Pallas kernel on `pallas-interpret`.  The kernel has no TPU
-lowering (`repro.kernels.TPU_LOWERING`), so on a TPU `auto` keeps the XLA
-merge.
+paper's BUM unit (DESIGN.md §3).  The merge and commit route through the
+`repro.kernels` registry as op "bum_scatter", by the rows' width: the XLA
+segment merge on `ref`; the output-stationary Pallas kernel (kernel.py) on
+`pallas-interpret`, and on `pallas-tpu` for rows up to
+`repro.kernels.TPU_MAX_WIDTH["bum_scatter"]` wide, which `auto` picks on a
+TPU.  Each trace of a
+commit counts `grid_update.commit.<backend>` in `repro.obs` (knob on).
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from . import kernel as _kernel
+from ...obs import metrics as _metrics
 from ...obs import trace as _trace
 
 
@@ -64,6 +67,20 @@ def _segment_commit(table: jnp.ndarray, idx_s: jnp.ndarray, vals_s: jnp.ndarray)
         return table.at[seg_idx].add(summed.astype(table.dtype), mode="drop")
 
 
+def _commit(table: jnp.ndarray, idx: jnp.ndarray, vals: jnp.ndarray, be,
+            presorted: bool) -> jnp.ndarray:
+    """Sort, merge and commit one stream on the resolved backend `be`."""
+    if _trace.enabled():
+        _metrics.counter(f"grid_update.commit.{be.name}").inc()
+    t = table.shape[0]
+    if be.use_pallas:
+        idx_s, vals_s = _sort_updates(idx, vals, t, _kernel.DEFAULT_TILE, presorted=presorted)
+        with _trace.stage("grid_update/commit", cat="kernels"):
+            return _kernel.bum_scatter_pallas(table, idx_s, vals_s, interpret=be.interpret)
+    idx_s, vals_s = _sort_updates(idx, vals, t, None, presorted=presorted)
+    return _segment_commit(table, idx_s, vals_s)
+
+
 @functools.partial(jax.jit, static_argnames=("backend", "presorted"))
 def merged_scatter_add(
     table: jnp.ndarray,
@@ -76,24 +93,17 @@ def merged_scatter_add(
     """table (T,F) += vals (M,F) at rows idx (M,) with BUM-merged writes.
 
     `backend` (a `repro.kernels` registry name or KernelBackend; None =>
-    process default) picks the commit stage: the XLA segment merge on ref,
-    the Pallas kernel otherwise.
+    process default) picks the merge and commit, resolved as op
+    "bum_scatter" at the rows' width F: the XLA segment merge on ref, the
+    Pallas kernel otherwise.
 
     presorted=True promises idx is already non-decreasing and skips the
     argsort — the BUM fast path for callers that control update order (the
     fused compacted-path VJP emits its table-gradient stream pre-sorted).
     """
     from .. import resolve_backend
-    be = resolve_backend(backend, op="bum_scatter")
-    t = table.shape[0]
-    if be.use_pallas:
-        idx_s, vals_s = _sort_updates(idx, vals, t, _kernel.DEFAULT_BLOCK,
-                                      presorted=presorted)
-        with _trace.stage("grid_update/commit", cat="kernels"):
-            return _kernel.bum_scatter_pallas(table, idx_s, vals_s, interpret=be.interpret)
-
-    idx_s, vals_s = _sort_updates(idx, vals, t, None, presorted=presorted)
-    return _segment_commit(table, idx_s, vals_s)
+    be = resolve_backend(backend, op="bum_scatter", width=table.shape[-1])
+    return _commit(table, idx, vals, be, presorted)
 
 
 @jax.jit
@@ -143,9 +153,8 @@ def windowed_scatter_add(
     `merged_scatter_add`.
     """
     from .. import resolve_backend
-    be = resolve_backend(backend, op="bum_scatter")
-    t = table.shape[0]
-    f = table.shape[1]
+    t, f = table.shape
+    be = resolve_backend(backend, op="bum_scatter", width=f)
 
     if idx.ndim == 1:
         m = idx.shape[0]
@@ -161,15 +170,7 @@ def windowed_scatter_add(
 
     def commit_window(tbl, inp):
         wi, wv = inp
-        if not presorted:
-            with _trace.stage("grid_update/sort", cat="kernels"):
-                order = jnp.argsort(wi)
-                wi, wv = wi[order], wv[order]
-        if be.use_pallas:
-            wi, wv = _sort_updates(wi, wv, t, _kernel.DEFAULT_BLOCK, presorted=True)
-            with _trace.stage("grid_update/commit", cat="kernels"):
-                return _kernel.bum_scatter_pallas(tbl, wi, wv, interpret=be.interpret), None
-        return _segment_commit(tbl, wi, wv), None
+        return _commit(tbl, wi, wv, be, presorted), None
 
     # Small static window counts (every per-step caller: the fused-step VJP
     # commits W=1; the F_D:F_C schedules make W<=4) unroll to straightline

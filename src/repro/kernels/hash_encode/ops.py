@@ -97,6 +97,7 @@ def make_hash_encode(
     """
     from .. import resolve_backend
     be = resolve_backend(backend, op="hash_encode")
+    commit_be = resolve_backend(backend, op="bum_scatter", width=n_features)
     resolutions = tuple(int(r) for r in resolutions)
     dense_flags = tuple(
         bool(x) for x in ref.level_is_dense(np.asarray(resolutions), table_size)
@@ -120,9 +121,10 @@ def make_hash_encode(
             idx, vals = _corner_updates(points, resolutions, dense_flags, table_size, grad)
             flat = jnp.zeros((num_l * table_size, n_features), jnp.float32)
             if merged_backward:
-                # commit stage follows the encoder's backend: pallas flavors use
-                # the BUM scatter kernel, ref stays on the XLA segment merge
-                flat = grid_update_ops.merged_scatter_add(flat, idx, vals, backend=be)
+                # the merge resolves as its own op from the same request: on a
+                # TPU `auto` runs the forward on ref and the merge in Pallas
+                flat = grid_update_ops.merged_scatter_add(flat, idx, vals,
+                                                          backend=commit_be)
             else:
                 flat = flat.at[idx].add(vals)
             grad_tables = flat.reshape(num_l, table_size, n_features).astype(tdtype)

@@ -147,3 +147,129 @@ def test_windowed_merge_matches_naive(m, window, rng):
     naive = ref.scatter_add(table, idx, vals)
     windowed = ops.windowed_scatter_add(table, idx, vals, window=window)
     np.testing.assert_allclose(np.asarray(windowed), np.asarray(naive), atol=1e-4, rtol=1e-4)
+
+
+# ---- the output-stationary Pallas merge (kernel.py), at small blocks ----
+
+_BT, _BS = 128, 128   # table rows per block, stream rows per tile
+
+
+def _stream(kind: str, t: int, rng) -> np.ndarray:
+    """Sorted address streams that stress the kernel's block and tile edges."""
+    if kind == "straddle":  # runs of 37 equal addresses across block and tile edges
+        idx = np.repeat(np.arange(_BT - 20, 3 * _BT + 20, 7), 37)
+    elif kind == "repeat":  # one address over several tiles (~320 rows on a coarse cell)
+        idx = np.concatenate([rng.integers(0, t, 90), np.full(3 * _BS + 5, _BT + 3),
+                              rng.integers(0, t, 60)])
+    elif kind == "untouched":  # whole blocks no row touches, inside one tile's range
+        idx = np.concatenate([rng.integers(0, _BT, 150), rng.integers(5 * _BT, 6 * _BT, 150)])
+    else:  # "sentinel": the stream ends in padding rows idx == T
+        idx = np.concatenate([rng.integers(0, t, 200), np.full(2 * _BS - 200 + 17, t)])
+    return np.sort(idx).astype(np.int32)
+
+
+@pytest.mark.parametrize("f", [1, 2, 4, 8])
+@pytest.mark.parametrize("kind", ["straddle", "repeat", "untouched", "sentinel"])
+def test_pallas_merge_edges_match_naive(kind, f, rng):
+    t = 8 * _BT
+    idx = _stream(kind, t, rng)
+    pad = (-idx.shape[0]) % _BS
+    idx = np.concatenate([idx, np.full(pad, t, np.int32)])
+    vals = rng.normal(size=(idx.shape[0], f)).astype(np.float32)
+    vals[idx == t] = 0.0
+    table = rng.normal(size=(t, f)).astype(np.float32)
+    live = idx < t
+    want = ref.scatter_add(jnp.asarray(table), jnp.asarray(idx[live]), jnp.asarray(vals[live]))
+    got = kernel.bum_scatter_pallas(jnp.asarray(table), jnp.asarray(idx), jnp.asarray(vals),
+                                    block_rows=_BT, tile=_BS, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-5)
+    if kind == "untouched":  # blocks 1-4 and 6-7 keep the input table exactly
+        for b in (1, 2, 3, 4, 6, 7):
+            rows = slice(b * _BT, (b + 1) * _BT)
+            np.testing.assert_array_equal(np.asarray(got)[rows], table[rows])
+
+
+@pytest.mark.parametrize("kind", ["every_tile_opens_a_block", "one_row_per_block"])
+def test_plan_stays_within_its_static_bound(kind, rng):
+    """Adversarial streams for the work-item count: the plan's items are the
+    (block, tile) overlaps, in order, and never pass blocks + tiles."""
+    n_blocks, n_tiles = 16, 15
+    t = n_blocks * _BT
+    if kind == "every_tile_opens_a_block":  # tile k holds the end of block k, start of k+1
+        idx = np.arange(n_tiles * _BS) + _BT // 2
+    else:  # rows spread one per block, then padding
+        idx = np.concatenate([np.arange(n_blocks) * _BT,
+                              np.full(n_tiles * _BS - n_blocks, t)])
+    idx = idx.astype(np.int32)
+    blocks, tiles, total = (np.asarray(a) for a in
+                            kernel.plan(jnp.asarray(idx), t, _BT, _BS))
+    assert blocks.shape == tiles.shape == (n_blocks + n_tiles,)
+    total = int(total[0])
+    want = sorted({(int(i) // _BT, j // _BS) for j, i in enumerate(idx) if i < t})
+    assert list(zip(blocks[:total].tolist(), tiles[:total].tolist())) == want
+    assert total <= n_blocks + n_tiles
+    vals = rng.normal(size=(idx.shape[0], 2)).astype(np.float32)
+    live = idx < t
+    want_tbl = ref.scatter_add(jnp.zeros((t, 2)), jnp.asarray(idx[live]), jnp.asarray(vals[live]))
+    got = kernel.bum_scatter_pallas(jnp.zeros((t, 2)), jnp.asarray(idx), jnp.asarray(vals),
+                                    block_rows=_BT, tile=_BS, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want_tbl), atol=1e-5, rtol=1e-6)
+
+
+def test_hash_grid_backward_routes_to_the_tpu_merge(monkeypatch):
+    """Under `auto` on a TPU the hash grid's forward stays on ref while its
+    backward's merge resolves to pallas-tpu and is counted there; rows too
+    wide for the kernel keep the XLA merge."""
+    import jax
+    from repro import kernels, obs
+    from repro.kernels.hash_encode import ops as he_ops, ref as he_ref
+    from repro.obs import metrics, trace
+
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
+    assert kernels.resolve_backend("auto", op="bum_scatter", width=2) is kernels.PALLAS_TPU
+    assert kernels.resolve_backend("auto", op="bum_scatter", width=64) is kernels.REF
+    with pytest.raises(ValueError, match="rows up to 8 wide"):
+        kernels.resolve_backend("pallas-tpu", op="bum_scatter", width=64)
+
+    res = tuple(int(r) for r in he_ref.level_resolutions(2, 4, 8))
+    encode = he_ops.make_hash_encode(res, 64, 2, backend="auto")
+    pts = jax.ShapeDtypeStruct((32, 3), jnp.float32)
+    tables = jax.ShapeDtypeStruct((2, 64, 2), jnp.float32)
+    was = trace.enabled()
+    trace.set_enabled(True)
+    obs.reset()
+    try:
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p, tb: encode(p, tb).sum(), argnums=1))(
+            pts, tables)
+        counts = {k: v["value"] for k, v in metrics.snapshot().items()}
+    finally:
+        obs.reset()
+        trace.set_enabled(was)
+    assert counts.get("grid_update.commit.pallas-tpu", 0) >= 1
+    assert "grid_update.commit.ref" not in counts
+    assert "pallas_call" in str(jaxpr)
+
+    wide = ops.merged_scatter_add.lower(jnp.zeros((16, 64)), jnp.zeros((40,), jnp.int32),
+                                        jnp.zeros((40, 64)), backend="auto").as_text()
+    assert "tpu_custom_call" not in wide and "scatter" in wide
+
+
+@pytest.mark.parametrize("max_items", [1, 3, 7])
+def test_pallas_merge_split_across_calls(max_items, rng):
+    """A stream with more work items than one call's SMEM lists hold is
+    merged by several calls in turn: bit-identical to one call, blocks cut
+    by a call's edge included."""
+    t = 8 * _BT
+    idx = np.concatenate([_stream("straddle", t, rng), _stream("repeat", t, rng)])
+    idx = np.sort(np.concatenate([idx, np.full((-idx.shape[0]) % _BS, t)])).astype(np.int32)
+    vals = rng.normal(size=(idx.shape[0], 2)).astype(np.float32)
+    vals[idx == t] = 0.0
+    table = jnp.asarray(rng.normal(size=(t, 2)).astype(np.float32))
+    args = (table, jnp.asarray(idx), jnp.asarray(vals))
+    one = kernel.bum_scatter_pallas(*args, block_rows=_BT, tile=_BS, interpret=True)
+    split = kernel.bum_scatter_pallas(*args, block_rows=_BT, tile=_BS, max_items=max_items,
+                                      interpret=True)
+    np.testing.assert_array_equal(np.asarray(split), np.asarray(one))
+    live = idx < t
+    want = ref.scatter_add(table, jnp.asarray(idx[live]), jnp.asarray(vals[live]))
+    np.testing.assert_allclose(np.asarray(split), np.asarray(want), atol=1e-4, rtol=1e-5)

@@ -20,10 +20,12 @@ from repro.core import Field, FieldConfig, Instant3DTrainer, TrainerConfig, occu
 from repro.core import trainer as trainer_lib
 from repro.core.rendering import RayBatch, RenderConfig
 from repro.kernels.fused_mlp import ops as mlp_ops
+from repro.kernels.grid_update import ops as gu_ops
 from repro.kernels.volume_render import ops as vr_ops
 
 V5E_HBM_BYTES = 16 * 2**30
 N_RAYS, N_SAMPLES = 4096, 48          # 196,608 points: the paper's ~200k per step
+LEVELS, LOG2_T, F = 16, 18, 2         # FieldConfig()'s density grid
 
 
 @pytest.fixture(scope="module")
@@ -83,9 +85,9 @@ def _mlp_programs(s):
         return mlp_ops.mlp3(x, w1, b1, w2, b2, w3, b3, backend="pallas-tpu").sum()
 
     # density head: L*F = 32 features -> 64 -> 1+15; color head: 32 + 16 SH -> 64 -> 64 -> 3
-    yield loss2, (s((n, 32)), s((32, 64)), s((64,)), s((64, 16)), s((16,)))
+    yield loss2, (s((n, 32)), s((32, 64)), s((64,)), s((64, 16)), s((16,))), range(5)
     yield loss3, (s((n, 48)), s((48, 64)), s((64,)), s((64, 64)), s((64,)),
-                  s((64, 3)), s((3,)))
+                  s((64, 3)), s((3,))), range(7)
 
 
 def _composite_programs(s):
@@ -94,20 +96,35 @@ def _composite_programs(s):
         return out.color.sum() + out.depth.sum() + out.opacity.sum()
 
     r, k = N_RAYS, N_SAMPLES
-    yield loss, (s((r, k)), s((r, k, 3)), s((r, k)), s((r, k)))
+    yield loss, (s((r, k)), s((r, k, 3)), s((r, k)), s((r, k))), range(4)
 
 
-PROGRAMS = {"mlp": _mlp_programs, "composite": _composite_programs}
+def _bum_scatter_programs(s):
+    """The hash grid backward's merge of its corner stream (N x 8 corners x
+    L levels rows) into the flat (L*T, F) gradient table: itself a
+    backward, so nothing of it is differentiated."""
+    m = N_RAYS * N_SAMPLES * 8 * LEVELS
+
+    def commit(table, idx, vals):
+        return gu_ops.merged_scatter_add(table, idx, vals, backend="auto")
+
+    yield commit, (s((LEVELS * 2**LOG2_T, F)), s((m,), jnp.int32), s((m, F))), ()
+
+
+# op -> (program, argument shapes, the arguments its backward differentiates)
+PROGRAMS = {"mlp": _mlp_programs, "composite": _composite_programs,
+            "bum_scatter": _bum_scatter_programs}
 
 
 @pytest.mark.parametrize("op", sorted(op for op, why in kernels.TPU_LOWERING.items()
                                       if why is None))
 def test_pallas_op_compiles_fwd_and_bwd(op, one_chip, on_tpu):
-    s = lambda shape: _shape(one_chip, shape)  # noqa: E731
-    for loss, args in PROGRAMS[op](s):
+    s = lambda shape, dtype=jnp.float32: _shape(one_chip, shape, dtype)  # noqa: E731
+    for loss, args, argnums in PROGRAMS[op](s):
         fwd = jax.jit(loss).lower(*args).compile()
         assert "tpu_custom_call" in fwd.as_text()
-        jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))).lower(*args).compile()
+        if argnums:
+            jax.jit(jax.grad(loss, argnums=tuple(argnums))).lower(*args).compile()
 
 
 def test_full_width_train_step_fits_v5e(one_chip, on_tpu):
